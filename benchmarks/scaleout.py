@@ -2,25 +2,24 @@
 
 The scale-out claim is end to end: a data-sharded collection
 (``repro.dist.ShardedPageStore``) served through the network frontend
-(``repro.serve.http.HttpFrontend`` via ``repro.launch.serve
---http-port``) answers MORE queries per second than the unsharded index
-at recall parity, and the admission-control surface (deadline sheds,
-in-flight 503s) actually sheds. This module is the external driver: the
-server runs in a SEPARATE process per shard count, and every request
-travels real HTTP + JSON.
+(``repro.serve.http.HttpFrontend``) answers MORE queries per second than
+the unsharded index at recall parity, and the admission-control surface
+(deadline sheds, in-flight 503s) actually sheds. The server runs in this
+process on its own thread (only one process may hold the chip), and
+every request travels real HTTP + JSON.
 
 Per shard count S in (1, 2, 4) it:
 
   * builds (or reloads from the bench cache) a one-collection database —
     unsharded ``PageANNIndex`` at S=1, ``ShardedPageStore`` otherwise,
-  * spawns ``python -m repro.launch.serve --smoke --db-dir ...
-    --http-port 0 --serve-forever``, scraping the printed frontend URL,
+  * loads it into a ``VectorService`` behind an ``HttpFrontend`` bound
+    to an ephemeral port,
   * hammers ``POST /search`` with the full query batch for R rounds,
     recording QPS, wall-clock percentiles and recall@10,
   * on the 2-shard server, exercises load shedding: a batch with a
     sub-millisecond ``deadline_ms`` must come back 504 with the engine's
     ``sheds`` counter advanced, and a concurrent stampede against
-    ``--max-inflight 2`` must surface 503s in
+    an in-flight cap of 2 must surface 503s in
     ``pageann_http_rejected_total{reason="inflight"}`` — both asserted
     from a real ``/metrics`` scrape.
 
@@ -41,9 +40,6 @@ import concurrent.futures
 import json
 import os
 import platform
-import subprocess
-import sys
-import threading
 import time
 import urllib.error
 import urllib.request
@@ -65,7 +61,6 @@ K = 10
 SHARD_COUNTS = (1, 2, 4)
 SCALING_FLOOR_2SHARD = 1.6
 RECALL_PARITY_SLACK = 0.02
-SERVER_START_TIMEOUT_S = 600
 
 
 # --------------------------------------------------------------- databases
@@ -96,54 +91,26 @@ def build_databases(x, cfg) -> dict[int, str]:
 
 # ------------------------------------------------------------------ server
 class Frontend:
-    """One ``repro.launch.serve --serve-forever`` subprocess + its URL."""
+    """One database served in THIS process: ``VectorService.load`` +
+    ``HttpFrontend`` on its own thread. The load generator shares the
+    process, so one process holds the device."""
 
     def __init__(self, db_dir: str, *, batch: int, max_inflight: int):
-        env = dict(os.environ)
-        env["PYTHONPATH"] = "src"
-        env.setdefault("JAX_PLATFORMS", "cpu")
-        env["PYTHONUNBUFFERED"] = "1"
-        self.proc = subprocess.Popen(
-            [
-                sys.executable, "-m", "repro.launch.serve", "--smoke",
-                "--db-dir", db_dir, "--http-port", "0", "--serve-forever",
-                "--batch", str(batch), "--max-inflight", str(max_inflight),
-            ],
-            stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True,
-            env=env,
-        )
-        self.url = None
-        self._lines: list[str] = []
-        deadline = time.monotonic() + SERVER_START_TIMEOUT_S
-        for line in self.proc.stdout:
-            self._lines.append(line)
-            if line.startswith("frontend: "):
-                self.url = line.split(" ", 1)[1].strip()
-                break
-            if time.monotonic() > deadline or self.proc.poll() is not None:
-                break
-        if self.url is None:
-            err = self.proc.stderr.read() if self.proc.stderr else ""
-            self.close()
-            raise RuntimeError(
-                "server never printed its frontend URL\n--- stdout ---\n"
-                + "".join(self._lines[-30:]) + "\n--- stderr ---\n"
-                + err[-3000:]
+        from repro.serve import HttpFrontend, VectorService
+
+        self.svc = VectorService.load(db_dir, batch_size=batch)
+        try:
+            self.fe = HttpFrontend(
+                self.svc, port=0, max_inflight=max_inflight
             )
-        # keep draining stdout so the server never blocks on a full pipe
-        self._drain = threading.Thread(
-            target=lambda: [None for _ in self.proc.stdout], daemon=True
-        )
-        self._drain.start()
+        except Exception:
+            self.svc.close()
+            raise
+        self.url = self.fe.url
 
     def close(self) -> None:
-        if self.proc.poll() is None:
-            self.proc.terminate()
-            try:
-                self.proc.wait(timeout=10)
-            except subprocess.TimeoutExpired:
-                self.proc.kill()
-                self.proc.wait(timeout=10)
+        self.fe.close()
+        self.svc.close()
 
     def __enter__(self):
         return self
@@ -210,7 +177,7 @@ def exercise_shedding(url: str, q: np.ndarray) -> dict:
     })
     deadline_code = code
     # 2) in-flight cap: a stampede of concurrent batches against
-    #    --max-inflight 2 must shed some requests with 503
+    #    an in-flight cap of 2 must shed some requests with 503
     payload = {"collection": "wiki", "queries": q[:8].tolist(), "k": K}
     with concurrent.futures.ThreadPoolExecutor(max_workers=8) as pool:
         codes = list(pool.map(
@@ -353,4 +320,7 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.jax_cache import use_persistent_cache
+
+    use_persistent_cache()
     main()

@@ -221,4 +221,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    from repro.launch.jax_cache import use_persistent_cache
+
+    use_persistent_cache()
     main()

@@ -1,88 +1,29 @@
-"""Version-portable wrappers over moving jax API surfaces.
+"""Mesh and ``shard_map`` with this repo's one policy for each.
 
-The mesh/shard_map surface moved between jax releases: ``shard_map`` lived
-in ``jax.experimental.shard_map`` (with a ``check_rep`` flag) before being
-promoted to ``jax.shard_map`` (flag renamed ``check_vma``), and
-``jax.make_mesh`` only grew ``axis_types`` after 0.4.x. Likewise
-``jax.pure_callback`` batching moved from the boolean ``vectorized=`` flag
-to the ``vmap_method=`` enum. Everything in this repo that touches these
-surfaces goes through here so the same code lowers on both the pinned CI
-jax and newer TPU toolchains.
+``make_mesh`` builds every mesh with ``Auto`` axis types, and
+``shard_map`` turns the replication check off (the sharded searches mix
+collectives with per-shard reductions, which the static checker rejects).
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Callable
 
 import jax
 
 
-@functools.cache
-def _callback_batch_kwargs() -> dict:
-    """How this jax spells "the callback handles batched args itself".
-
-    ``vmap_method="expand_dims"`` (new spelling) and ``vectorized=True``
-    (old spelling) agree for callbacks whose every argument is mapped: the
-    host function is invoked ONCE per batched call with a leading batch
-    axis on each argument and must return results with the same leading
-    axis — exactly what the streaming page fetcher wants (one host
-    round-trip per hop for the whole vmapped query batch, not one per
-    query).
-    """
-    import inspect
-
-    try:
-        params = inspect.signature(jax.pure_callback).parameters
-    except (TypeError, ValueError):
-        return {"vectorized": True}
-    if "vmap_method" in params:
-        return {"vmap_method": "expand_dims"}
-    return {"vectorized": True}
-
-
-def pure_callback_batched(callback: Callable, result_shape_dtypes, *args):
-    """``jax.pure_callback`` that batches under vmap with one host call.
-
-    ``callback`` must accept arguments with arbitrary leading batch axes
-    and return arrays with those axes prepended to the declared result
-    shapes.
-    """
-    return jax.pure_callback(
-        callback, result_shape_dtypes, *args, **_callback_batch_kwargs()
-    )
-
-
 def make_mesh(axis_shapes, axis_names) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` without version-specific ``axis_types`` kwargs."""
-    if hasattr(jax, "make_mesh"):
-        try:
-            axis_type = getattr(jax.sharding, "AxisType", None)
-            if axis_type is not None:
-                return jax.make_mesh(
-                    tuple(axis_shapes), tuple(axis_names),
-                    axis_types=(axis_type.Auto,) * len(tuple(axis_names)),
-                )
-            return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
-        except TypeError:
-            return jax.make_mesh(tuple(axis_shapes), tuple(axis_names))
-    from jax.experimental import mesh_utils
-
-    devices = mesh_utils.create_device_mesh(tuple(axis_shapes))
-    return jax.sharding.Mesh(devices, tuple(axis_names))
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    names = tuple(axis_names)
+    return jax.make_mesh(
+        tuple(axis_shapes), names,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+    )
 
 
 def shard_map(
     f: Callable, *, mesh: jax.sharding.Mesh, in_specs: Any, out_specs: Any
 ) -> Callable:
-    """``shard_map`` with replication checking off (we mix collectives with
-    per-shard reductions, which the static checker rejects either way)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    """``jax.shard_map`` with the replication check off."""
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
     )

@@ -692,16 +692,17 @@ def _stream_search_fn(
     the compiled filter (frozen tuples) participates by value, one
     executable per distinct predicate.
     """
-    from repro.core import compat
-
     kwargs = _impl_kwargs(params, capacity, mode)
     rows, lanes = fetcher.record_shape
 
     def fetch(ids: jnp.ndarray) -> jnp.ndarray:
-        return compat.pure_callback_batched(
+        # expand_dims: under vmap the fetcher is called ONCE per hop with
+        # the batch axis leading, not once per query
+        return jax.pure_callback(
             fetcher,
             jax.ShapeDtypeStruct(ids.shape + (rows, lanes), jnp.float32),
             ids,
+            vmap_method="expand_dims",
         )
 
     @jax.jit

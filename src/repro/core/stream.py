@@ -4,7 +4,7 @@ The paper's regime is disk-resident search under a memory budget; this
 module is the half of it that lives on the host. A :class:`PageFetcher`
 wraps the ``np.memmap`` of ``pages.bin`` and serves per-hop record
 requests from the jitted search loop (reached through
-``compat.pure_callback_batched`` — one host round-trip per hop for the
+a batched ``jax.pure_callback`` — one host round-trip per hop for the
 whole vmapped query batch):
 
   * requested page ids arrive with arbitrary leading batch axes,

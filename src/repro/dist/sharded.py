@@ -34,8 +34,10 @@ import dataclasses
 import math
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import distributed as dist
 from repro.core import persist
@@ -82,6 +84,14 @@ class ShardedPageStore:
     shards: list
     parts: list                      # list[np.ndarray] global ids per shard
     cfg: PageANNConfig
+    # mesh fan-out state, built on first use: mesh -> placed shards, and
+    # (mesh, per-shard params) -> jitted shard_map search
+    _placed: dict = dataclasses.field(
+        default_factory=dict, repr=False, compare=False
+    )
+    _mesh_fns: dict = dataclasses.field(
+        default_factory=dict, repr=False, compare=False
+    )
 
     def __post_init__(self):
         if len(self.shards) != len(self.parts):
@@ -220,10 +230,13 @@ class ShardedPageStore:
                 f"{self.num_shards} shards"
             )
         sp = shard_params_for(p, self.num_shards)
-        sh = self.to_sharded_index()
-        fn, _ = dist.make_sharded_search(
-            mesh, self.cfg, sh.capacity, k=p.k, params=sp
-        )
+        sh = self.mesh_data(mesh)
+        fn = self._mesh_fns.get((mesh, sp))
+        if fn is None:
+            fn, _ = dist.make_sharded_search(
+                mesh, self.cfg, sh.capacity, k=p.k, params=sp
+            )
+            self._mesh_fns[(mesh, sp)] = fn
         with mesh:
             ids, tag, d, ios = fn(sh.data, jnp.asarray(queries, jnp.float32))
         local = np.asarray(ids)
@@ -244,6 +257,20 @@ class ShardedPageStore:
             ids=out, dists=dd, ios=np.asarray(ios), hops=zeros,
             cache_hits=zeros,
         )
+
+    def mesh_data(self, mesh) -> dist.ShardedIndex:
+        """The stacked shards placed on ``mesh``: shard s's arrays live on
+        the devices at position s of its ``data`` axis. Stacked and copied
+        once per mesh, not once per search."""
+        placed = self._placed.get(mesh)
+        if placed is None:
+            sh = self.to_sharded_index()
+            on_data = NamedSharding(mesh, P("data"))
+            placed = sh._replace(data=jax.device_put(
+                sh.data, jax.tree.map(lambda _: on_data, sh.data)
+            ))
+            self._placed[mesh] = placed
+        return placed
 
     def to_sharded_index(self) -> dist.ShardedIndex:
         """Stack the sub-indexes into the shard_map input layout.  The

@@ -1,7 +1,8 @@
 """Pallas TPU kernel: batched squared-L2 distance via the MXU.
 
 dist(q, x) = |q|^2 - 2 q.x + |x|^2 — the -2qx term is a (bq, d) x (d, bx)
-matmul that lands on the MXU; the norms are VPU reductions. Tiles are
+matmul that lands on the MXU at HIGHEST precision (the TPU's default
+runs f32 operands in bf16 passes); the norms are VPU reductions. Tiles are
 (block_q, d) and (block_x, d) VMEM blocks; d stays unblocked (ANN dims are
 <= 1024, well within VMEM at f32).
 """
@@ -22,6 +23,7 @@ def _l2_kernel(q_ref, x_ref, o_ref):
     qx = jax.lax.dot_general(
         q, x,
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )                                                     # (bq, bx)
     o_ref[...] = qq - 2.0 * qx + xx

@@ -1,11 +1,12 @@
 """Dispatching wrappers: Pallas kernel on TPU, pure-jnp oracle elsewhere.
 
-The search path calls these; on this CPU container they resolve to the
-oracles (fast under XLA:CPU), while tests force ``impl='pallas'`` with
-interpret=True to validate the TPU kernels themselves. The backend probe
-is resolved once per process (``_backend``) instead of re-querying
-``jax.default_backend()`` on every hot-path dispatch; tests still override
-the choice explicitly via ``impl=``.
+The search path calls these; on a TPU they run the Pallas kernels, on
+the CPU the oracles (fast under XLA:CPU), while tests force
+``impl='pallas'`` with interpret=True to validate the kernels
+themselves. The backend probe is resolved once per process
+(``_backend``) instead of re-querying ``jax.default_backend()`` on every
+hot-path dispatch; tests still override the choice explicitly via
+``impl=``.
 """
 from __future__ import annotations
 
@@ -74,15 +75,21 @@ def delta_scan(q, vecs, live, k: int, *, mask=None, impl: str | None = None,
     elsewhere), masks dead/padded rows to INF, and selects the per-query
     ascending top-k with ``lax.top_k``. ``mask`` (C,) bool is the
     filtered-search predicate over delta rows — rows failing it score INF
-    exactly like dead rows (None leaves the program unchanged). Returns
-    (dists (Q, k) f32, slots (Q, k) int32 row indices into ``vecs``);
-    non-finite entries mean fewer than k live rows.
+    exactly like dead rows (None leaves the program unchanged). The k
+    selected rows are re-scored as sums of squared differences, so the
+    returned distances are exact (a stored vector is 0.0 from itself) on
+    every backend, and re-ranked by them. Returns (dists (Q, k) f32,
+    slots (Q, k) int32 row indices into ``vecs``); non-finite entries mean
+    fewer than k live rows.
     """
     d = l2_distance(q, vecs, impl=impl, interpret=interpret)
     keep = live if mask is None else live & mask
     d = jnp.where(keep[None, :], d, jnp.inf)
     neg, slots = jax.lax.top_k(-d, k)
-    return -neg, slots.astype(jnp.int32)
+    diff = vecs[slots].astype(jnp.float32) - q.astype(jnp.float32)[:, None, :]
+    exact = jnp.where(jnp.isfinite(neg), (diff * diff).sum(-1), jnp.inf)
+    neg, order = jax.lax.top_k(-exact, k)
+    return -neg, jnp.take_along_axis(slots, order, axis=1).astype(jnp.int32)
 
 
 def page_scan(recs, page_ids, q, lut, *, capacity: int, dim: int, rp: int,

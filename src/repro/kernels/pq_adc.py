@@ -1,10 +1,11 @@
 """Pallas TPU kernel: PQ asymmetric distance computation (ADC).
 
 TPU adaptation (DESIGN.md §2): the CPU-idiomatic per-code LUT *gather* is
-replaced by a one-hot contraction — codes (bn, M) select rows of the LUT
-(M, K) by building a (bn, M*K) one-hot mask and contracting against the
-flattened LUT on the MXU. For M*K = 16*256 = 4K lanes this is a single
-(bn x 4K) x (4K,) matvec per block: gather-free, systolic-friendly.
+replaced by a one-hot select — per subspace j, the codes column (bn, 1)
+is compared against a lane iota over the K centroids, the hits pick
+``lut[j]`` out of a (bn, K) tile, and a lane sum folds it to (bn, 1). The
+M subspaces accumulate into one column: gather-free, every value on the
+(8, 128) tile, and exact f32 (a select, not a multiply).
 """
 from __future__ import annotations
 
@@ -16,18 +17,17 @@ from jax.experimental import pallas as pl
 
 
 def _adc_kernel(codes_ref, lut_ref, o_ref):
-    codes = codes_ref[...].astype(jnp.int32)     # (bn, M)
-    lut = lut_ref[...].astype(jnp.float32)       # (M, K)
+    codes = codes_ref[...]                        # (bn, M) int32
+    lut = lut_ref[...]                            # (M, K) f32
     m, k = lut.shape
-    # one-hot over the K axis, keyed by code value
-    iota_k = jax.lax.broadcasted_iota(jnp.int32, (codes.shape[0], m, k), 2)
-    onehot = (codes[:, :, None] == iota_k).astype(jnp.float32)  # (bn, M, K)
-    flat = onehot.reshape(codes.shape[0], m * k)
-    o_ref[...] = jax.lax.dot_general(
-        flat, lut.reshape(m * k, 1),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                             # (bn, 1)
+    iota_k = jax.lax.broadcasted_iota(jnp.int32, (codes.shape[0], k), 1)
+    acc = jnp.zeros((codes.shape[0], 1), jnp.float32)
+    for j in range(m):
+        hit = codes[:, j : j + 1] == iota_k       # (bn, K)
+        acc = acc + jnp.sum(
+            jnp.where(hit, lut[j : j + 1, :], 0.0), axis=-1, keepdims=True
+        )
+    o_ref[...] = acc                              # (bn, 1)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
@@ -41,7 +41,7 @@ def pq_adc(
     """codes: (N, M) uint8, lut: (M, K) f32 -> (N,) f32 ADC distances."""
     n0, m = codes.shape
     n = -(-n0 // block_n) * block_n
-    cp = jnp.pad(codes, ((0, n - n0), (0, 0)))
+    cp = jnp.pad(codes.astype(jnp.int32), ((0, n - n0), (0, 0)))
     out = pl.pallas_call(
         _adc_kernel,
         grid=(n // block_n,),
@@ -52,5 +52,5 @@ def pq_adc(
         out_specs=pl.BlockSpec((block_n, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
         interpret=interpret,
-    )(cp, lut)
+    )(cp, lut.astype(jnp.float32))
     return out[:n0, 0]

@@ -5,18 +5,25 @@ assert_allclose the kernel (interpret=True on CPU) against these.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import record_layout
 
 
 def l2_distance_ref(q: jnp.ndarray, x: jnp.ndarray) -> jnp.ndarray:
-    """Squared L2 distances. q: (Bq, d), x: (Nx, d) -> (Bq, Nx) f32."""
+    """Squared L2 distances. q: (Bq, d), x: (Nx, d) -> (Bq, Nx) f32.
+
+    Expanded form |q|^2 - 2q.x + |x|^2, the oracle of the ``l2dist``
+    kernel; the cross term runs at HIGHEST precision. The form need not
+    round to 0 for q == x: callers that need an exact self-match
+    (``ops.delta_scan``) re-score their top-k by differences.
+    """
     q = q.astype(jnp.float32)
     x = x.astype(jnp.float32)
     return (
         (q * q).sum(-1)[:, None]
-        - 2.0 * q @ x.T
+        - 2.0 * jnp.matmul(q, x.T, precision=jax.lax.Precision.HIGHEST)
         + (x * x).sum(-1)[None, :]
     )
 

@@ -58,6 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.registry import ARCH_IDS, get_arch
+from repro.launch.jax_cache import use_persistent_cache
 from repro.models import transformer as tf
 from repro.train.step import init_train_state
 
@@ -279,6 +280,7 @@ def main(argv=None):
              "with metrics() — exits nonzero on mismatch",
     )
     args = ap.parse_args(argv)
+    use_persistent_cache()
     if args.obs_selfcheck and args.metrics_port is None:
         raise SystemExit("--obs-selfcheck needs --metrics-port")
     if args.http_port is not None and not args.db_dir:
