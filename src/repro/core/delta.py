@@ -488,6 +488,7 @@ class MutableIndex:
             ios=np.asarray(res.ios),
             hops=np.asarray(res.hops),
             cache_hits=np.asarray(res.cache_hits),
+            shared_reads=res.shared_reads,
         )
 
     @staticmethod

@@ -414,6 +414,7 @@ class PageANNIndex:
             ios=np.asarray(res.ios),
             hops=np.asarray(res.hops),
             cache_hits=np.asarray(res.cache_hits),
+            shared_reads=np.asarray(res.shared_reads),
         )
 
     def profile(

@@ -36,6 +36,17 @@ argument — one compiled executable per distinct value, over one index.
 I/O and cache-hit counters reproduce the paper's "Mean I/Os" metric.
 Later async-prefetch / cache-eviction work should extend the transition
 functions, not re-inline the loop.
+
+Each stage of a hop runs under a ``jax.named_scope``, so
+a device op's scope in a profiler trace names its stage: ``hop_select``,
+``hop_scan`` (the page-scan kernels and their member masking),
+``hop_fetch`` (the streamed path's host callback alone),
+``hop_cache_probe``, ``hop_nbr_adc``, ``hop_cand_probe``, ``hop_dedupe``
+and ``hop_merge``. ``batch_search`` also keeps each lane's scheduled
+pages per hop and reports, per query, the reads of a page that a
+lower-numbered lane scanned at the same hop (``SearchResult.shared_reads``):
+what a hop kernel that reads each shared page once would save. That
+bookkeeping runs under a scope of its own, ``shared_reads``.
 """
 from __future__ import annotations
 
@@ -114,6 +125,10 @@ class SearchResult(NamedTuple):
     ios: jnp.ndarray      # (Q,) page reads that went to 'disk'
     hops: jnp.ndarray     # (Q,) while_loop iterations
     cache_hits: jnp.ndarray  # (Q,) page reads served by the warmed cache
+    # (Q,) page reads (ios + cache hits) of a page that a lower-numbered
+    # query of the same batch read at the same hop; None where the search
+    # keeps no per-hop trail (profile_search, merged or sharded results)
+    shared_reads: jnp.ndarray | None = None
 
 
 class BeamState(NamedTuple):
@@ -122,7 +137,7 @@ class BeamState(NamedTuple):
     The two adaptive fields are ``None`` — absent from the pytree — unless
     per-query early termination is on (``AdaptiveParams.patience``), so the
     non-adaptive loop carries the exact pre-adaptive structure and compiles
-    to the same program.
+    to the same program. ``trail`` is carried by ``_search_one`` alone.
     """
 
     cand_ids: jnp.ndarray   # (L,) candidate vector ids, PAD padded
@@ -139,6 +154,8 @@ class BeamState(NamedTuple):
     # hops failed to improve it by more than epsilon
     frontier: jnp.ndarray | None = None   # () f32
     stall: jnp.ndarray | None = None      # () int32 patience counter
+    # (max_hops, b) int32: the pages scheduled at each hop, PAD padded
+    trail: jnp.ndarray | None = None
 
 
 def _mask_dups_keep_first(ids: jnp.ndarray, d: jnp.ndarray) -> jnp.ndarray:
@@ -154,6 +171,29 @@ def _mask_dups_keep_first(ids: jnp.ndarray, d: jnp.ndarray) -> jnp.ndarray:
     dup_sorted = jnp.concatenate([jnp.array([False]), s[1:] == s[:-1]])
     dup = jnp.zeros((n,), bool).at[spos].set(dup_sorted)
     return jnp.where(dup & (ids != PAD), INF, d)
+
+
+@jax.named_scope("shared_reads")
+def _shared_reads(trail: jnp.ndarray) -> jnp.ndarray:
+    """(Q,) reads of a page that a lower-numbered lane read at the same hop,
+    from the lanes' (Q, H, b) trails of scheduled pages.
+
+    A stable sort of each hop's Q*b reads by page lines up a page's reads
+    in lane order, so every read but the first of its run is shared (a
+    lane schedules a page at most once a hop); a scatter to each read's
+    own position puts the flags back in lane order. O(Q*b log(Q*b)) a hop
+    (a second sort in the scatter's place adds seconds to a TPU compile).
+    """
+    q, h, b = trail.shape
+    reads = jnp.swapaxes(trail, 0, 1).reshape(h, q * b)   # read j: lane j // b
+    pos = jax.lax.broadcasted_iota(jnp.int32, reads.shape, 1)
+    pages, pos = jax.lax.sort((reads, pos), num_keys=1, is_stable=True)
+    shared = (pages[:, 1:] == pages[:, :-1]) & (pages[:, 1:] != PAD)
+    shared = jnp.pad(shared, ((0, 0), (1, 0))).astype(jnp.int32)
+    hop = jax.lax.broadcasted_iota(jnp.int32, reads.shape, 0)
+    shared = jnp.zeros_like(shared).at[hop, pos].set(
+        shared, unique_indices=True)
+    return shared.reshape(h, q, b).sum((0, 2))
 
 
 def _top_k_merge(d: jnp.ndarray, k: int) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -225,6 +265,7 @@ def init_state(
     )
 
 
+@jax.named_scope("hop_select")
 def select_batch(
     state: BeamState, *, capacity: int, io_batch: int
 ) -> tuple[BeamState, jnp.ndarray]:
@@ -366,88 +407,96 @@ def score_page_batch(
     rp = data.nbr_ids.shape[1]
     safe = jnp.maximum(batch, 0)
     fetched = batch >= 0
+    scan = dict(capacity=cap, dim=q.shape[0], rp=rp,
+                compute_adc=mode != MemoryMode.MEM_ALL.value)
 
-    member_mask = (
-        page_member_mask(meta, cfilter, safe, capacity=cap)
-        if meta is not None and cfilter is not None
-        else None
-    )
-    compute_adc = mode != MemoryMode.MEM_ALL.value
-    if fetch is None:
-        ex, est_disk = ops.page_scan(
-            data.page_recs, safe, q, disk_lut,
-            capacity=cap, dim=q.shape[0], rp=rp, compute_adc=compute_adc,
-            member_mask=member_mask,
+    with jax.named_scope("hop_scan"):
+        # the mask is a function of the page id alone, so the SAME (b, cap)
+        # mask applies to the resident and staged lanes of the hop
+        scan["member_mask"] = (
+            page_member_mask(meta, cfilter, safe, capacity=cap)
+            if meta is not None and cfilter is not None
+            else None
         )
-    else:
-        slot = data.resident_map[safe]                  # (b,)
-        resident = slot >= 0
-        # host fetch only what the device lacks; everything else (resident
-        # pages, unselected PAD lanes) is masked to -1 and comes back as a
-        # zero record whose scores are discarded by the per-lane merge /
-        # downstream validity masks
-        staged = fetch(jnp.where(fetched & ~resident, safe, PAD))
-        # the mask is a function of the page id alone, so the SAME (b,
-        # cap) mask applies to the resident and staged lanes of the hop
-        ex_r, est_r = ops.page_scan(
-            data.page_recs, jnp.where(resident, slot, 0), q, disk_lut,
-            capacity=cap, dim=q.shape[0], rp=rp, compute_adc=compute_adc,
-            member_mask=member_mask,
-        )
-        ex_s, est_s = ops.page_scan_recs(
-            staged, q, disk_lut,
-            capacity=cap, dim=q.shape[0], rp=rp, compute_adc=compute_adc,
-            member_mask=member_mask,
-        )
-        ex = jnp.where(resident[:, None], ex_r, ex_s)
-        est_disk = (
-            None if est_r is None
-            else jnp.where(resident[:, None], est_r, est_s)
-        )
-    slots = jnp.arange(cap)[None, :]
-    ex = jnp.where(slots < data.member_count[safe][:, None], ex, INF)
-    ex = jnp.where(fetched[:, None], ex, INF)
-    member_ids = (batch[:, None] * capacity + slots).astype(jnp.int32)
+        if fetch is None:
+            ex, est_disk = ops.page_scan(
+                data.page_recs, safe, q, disk_lut, **scan
+            )
+        else:
+            slot = data.resident_map[safe]                  # (b,)
+            resident = slot >= 0
+            # host fetch only what the device lacks; everything else
+            # (resident pages, unselected PAD lanes) is masked to -1 and
+            # comes back as a zero record whose scores are discarded by the
+            # per-lane merge / downstream validity masks
+            missing = jnp.where(fetched & ~resident, safe, PAD)
+    if fetch is not None:
+        with jax.named_scope("hop_fetch"):
+            staged = fetch(missing)
+        with jax.named_scope("hop_scan"):
+            ex_r, est_r = ops.page_scan(
+                data.page_recs, jnp.where(resident, slot, 0), q, disk_lut,
+                **scan,
+            )
+            ex_s, est_s = ops.page_scan_recs(staged, q, disk_lut, **scan)
+            ex = jnp.where(resident[:, None], ex_r, ex_s)
+            est_disk = (
+                None if est_r is None
+                else jnp.where(resident[:, None], est_r, est_s)
+            )
+    with jax.named_scope("hop_scan"):
+        slots = jnp.arange(cap)[None, :]
+        ex = jnp.where(slots < data.member_count[safe][:, None], ex, INF)
+        ex = jnp.where(fetched[:, None], ex, INF)
+        member_ids = (batch[:, None] * capacity + slots).astype(jnp.int32)
 
     # warmed page cache (Sec 4.3): sorted-membership test
     if data.cached_pages.shape[0] > 0:
-        pos = jnp.searchsorted(data.cached_pages, safe)
-        pos = jnp.minimum(pos, data.cached_pages.shape[0] - 1)
-        in_cache = data.cached_pages[pos] == safe
+        with jax.named_scope("hop_cache_probe"):
+            pos = jnp.searchsorted(data.cached_pages, safe)
+            pos = jnp.minimum(pos, data.cached_pages.shape[0] - 1)
+            in_cache = data.cached_pages[pos] == safe
     else:
         in_cache = jnp.zeros_like(fetched)
     io_delta = (fetched & ~in_cache).sum().astype(jnp.int32)
     hit_delta = (fetched & in_cache).sum().astype(jnp.int32)
 
     # neighbor estimates (Fig. 6 steps 3-4) per the coordination mode
-    page_nids = data.nbr_ids[safe]                          # (b, Rp)
-    flat_nids = page_nids.reshape(-1)                       # (b*Rp,)
-    valid_n = (
-        (jnp.arange(rp)[None, :] < data.nbr_count[safe][:, None]).reshape(-1)
-        & (flat_nids != PAD)
-        & fetched.repeat(rp)
-    )
-    safe_nids = jnp.maximum(flat_nids, 0)
-    if mode == MemoryMode.DISK_ONLY.value:
-        est = est_disk.reshape(-1)
-    elif mode == MemoryMode.MEM_ALL.value:
-        est = ops.pq_adc(data.mem_codes[safe_nids], mem_lut)
-    else:  # HYBRID: prefer the higher-accuracy in-memory codes
-        est_mem = ops.pq_adc(data.mem_codes[safe_nids], mem_lut)
-        est = jnp.where(data.mem_mask[safe_nids], est_mem, est_disk.reshape(-1))
-    est = jnp.where(valid_n, est, INF)
-    # skip neighbors on already-visited pages
-    est = jnp.where(state.page_vis[safe_nids // capacity], INF, est)
+    with jax.named_scope("hop_nbr_adc"):
+        page_nids = data.nbr_ids[safe]                      # (b, Rp)
+        flat_nids = page_nids.reshape(-1)                   # (b*Rp,)
+        valid_n = (
+            (jnp.arange(rp)[None, :] < data.nbr_count[safe][:, None])
+            .reshape(-1)
+            & (flat_nids != PAD)
+            & fetched.repeat(rp)
+        )
+        safe_nids = jnp.maximum(flat_nids, 0)
+        if mode == MemoryMode.DISK_ONLY.value:
+            est = est_disk.reshape(-1)
+        elif mode == MemoryMode.MEM_ALL.value:
+            est = ops.pq_adc(data.mem_codes[safe_nids], mem_lut)
+        else:  # HYBRID: prefer the higher-accuracy in-memory codes
+            est_mem = ops.pq_adc(data.mem_codes[safe_nids], mem_lut)
+            est = jnp.where(
+                data.mem_mask[safe_nids], est_mem, est_disk.reshape(-1)
+            )
+        est = jnp.where(valid_n, est, INF)
+        # skip neighbors on already-visited pages
+        est = jnp.where(state.page_vis[safe_nids // capacity], INF, est)
     # skip neighbors already in the candidate set: sorted membership probe
-    sorted_cand = jnp.sort(state.cand_ids)
-    pos = jnp.searchsorted(sorted_cand, flat_nids)
-    pos = jnp.minimum(pos, sorted_cand.shape[0] - 1)
-    est = jnp.where(sorted_cand[pos] == flat_nids, INF, est)
+    with jax.named_scope("hop_cand_probe"):
+        sorted_cand = jnp.sort(state.cand_ids)
+        pos = jnp.searchsorted(sorted_cand, flat_nids)
+        pos = jnp.minimum(pos, sorted_cand.shape[0] - 1)
+        est = jnp.where(sorted_cand[pos] == flat_nids, INF, est)
     # dedupe within this batch
-    est = _mask_dups_keep_first(flat_nids, est)
+    with jax.named_scope("hop_dedupe"):
+        est = _mask_dups_keep_first(flat_nids, est)
     return member_ids.ravel(), ex.ravel(), flat_nids, est, io_delta, hit_delta
 
 
+@jax.named_scope("hop_merge")
 def merge(
     state: BeamState,
     member_ids: jnp.ndarray,
@@ -538,7 +587,7 @@ def _search_one(
     state = init_state(
         q, data, disk_lut, beam=beam, k=k, entries=entries,
         entry_slack=entry_slack, min_entries=min_entries, patience=patience,
-    )
+    )._replace(trail=jnp.full((max_hops, io_batch), PAD, jnp.int32))
 
     def cond(state: BeamState):
         live = (
@@ -558,6 +607,11 @@ def _search_one(
         state, batch = select_batch(
             state, capacity=capacity, io_batch=io_batch
         )
+        with jax.named_scope("shared_reads"):
+            # a select, not a scatter: cheaper in the vmapped loop
+            at_hop = jnp.arange(max_hops)[:, None] == state.hops
+            state = state._replace(
+                trail=jnp.where(at_hop, batch[None, :], state.trail))
         mids, md, nids, nd, io_delta, hit_delta = score_page_batch(
             q, data, batch, state, disk_lut, mem_lut,
             capacity=capacity, mode=mode, fetch=fetch,
@@ -569,7 +623,8 @@ def _search_one(
         )
 
     state = jax.lax.while_loop(cond, body, state)
-    return state.res_ids, state.res_d, state.io, state.hops, state.cache_hits
+    return (state.res_ids, state.res_d, state.io, state.hops,
+            state.cache_hits, state.trail)
 
 
 def _batch_search_impl(
@@ -610,8 +665,9 @@ def _batch_search_impl(
         meta=meta,
         cfilter=cfilter,
     )
-    ids, dists, ios, hops, hits = jax.vmap(fn)(queries, valid)
-    return SearchResult(ids=ids, dists=dists, ios=ios, hops=hops, cache_hits=hits)
+    ids, dists, ios, hops, hits, trail = jax.vmap(fn)(queries, valid)
+    return SearchResult(ids=ids, dists=dists, ios=ios, hops=hops,
+                        cache_hits=hits, shared_reads=_shared_reads(trail))
 
 
 def _impl_kwargs(params: SearchParams, capacity: int, mode: str) -> dict:

@@ -32,6 +32,8 @@ import time
 
 import numpy as np
 
+from repro.obs.trace import phase
+
 PAD = -1
 
 # default staging-cache size (pages). Big enough to absorb the hub-page
@@ -81,9 +83,10 @@ class PageFetcher:
         # latency window)
         self._wall_window: collections.deque = collections.deque(maxlen=4096)
         # optional span tracer (duck-typed, see repro.obs.trace.Tracer);
-        # attached by the serving engine so per-hop host fetches show up
-        # as child spans of the dispatch that triggered them. The fetcher
-        # stamps spans with the tracer's own clock.
+        # attached by the serving engine so per-hop host fetches land in
+        # its ring buffer beside the dispatch that triggered them, stamped
+        # with the tracer's own clock. A profiler trace gets the
+        # ``fetch.page_fetch`` span without it.
         self.tracer = None
 
     @property
@@ -95,41 +98,37 @@ class PageFetcher:
         return int(self._recs.shape[1]), int(self._recs.shape[2])
 
     def __call__(self, ids) -> np.ndarray:
-        t0 = time.perf_counter()
-        ids = np.asarray(ids)
-        flat = ids.reshape(-1).astype(np.int64)
-        rows, lanes = self.record_shape
-        out = np.zeros((flat.size, rows, lanes), np.float32)
-        with self._lock:
-            fetched0 = self._pages_fetched
-            for j, pid in enumerate(flat):
-                if pid < 0:
-                    continue
-                pid = int(pid)
-                rec = self._stage.get(pid)
-                if rec is not None:
-                    self._stage.move_to_end(pid)
-                    self._fetch_hits += 1
-                else:
-                    # THE disk read: one page record off the memmap
-                    rec = np.asarray(self._recs[pid], np.float32)
-                    self._pages_fetched += 1
-                    self._stage[pid] = rec
-                    if len(self._stage) > self._stage_pages:
-                        self._stage.popitem(last=False)     # evict LRU
-                out[j] = rec
-            wall = time.perf_counter() - t0
-            self._fetch_wall_s += wall
-            self._wall_window.append(wall)
-            misses = self._pages_fetched - fetched0
-        tr = self.tracer
-        if tr is not None and tr.enabled:
-            t1 = tr.now()
-            tr.add("page_fetch", t1 - wall, t1, cat="host-fetch",
-                   track="host-fetch",
-                   args={"requested": int((flat >= 0).sum()),
-                         "misses": misses})
-        return out.reshape(ids.shape + (rows, lanes))
+        with phase("fetch.page_fetch", self.tracer, cat="host-fetch",
+                   track="host-fetch") as span:
+            t0 = time.perf_counter()
+            ids = np.asarray(ids)
+            flat = ids.reshape(-1).astype(np.int64)
+            rows, lanes = self.record_shape
+            out = np.zeros((flat.size, rows, lanes), np.float32)
+            with self._lock:
+                fetched0 = self._pages_fetched
+                for j, pid in enumerate(flat):
+                    if pid < 0:
+                        continue
+                    pid = int(pid)
+                    rec = self._stage.get(pid)
+                    if rec is not None:
+                        self._stage.move_to_end(pid)
+                        self._fetch_hits += 1
+                    else:
+                        # THE disk read: one page record off the memmap
+                        rec = np.asarray(self._recs[pid], np.float32)
+                        self._pages_fetched += 1
+                        self._stage[pid] = rec
+                        if len(self._stage) > self._stage_pages:
+                            self._stage.popitem(last=False)   # evict LRU
+                    out[j] = rec
+                wall = time.perf_counter() - t0
+                self._fetch_wall_s += wall
+                self._wall_window.append(wall)
+                misses = self._pages_fetched - fetched0
+            span.annotate(requested=int((flat >= 0).sum()), misses=misses)
+            return out.reshape(ids.shape + (rows, lanes))
 
     # ------------------------------------------------------------- counters
     def fetch_stats(self) -> dict:

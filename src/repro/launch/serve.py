@@ -37,8 +37,10 @@ Observability (``repro.obs``): ``--metrics-port PORT`` starts the stdlib
 HTTP sidecar serving ``/metrics`` (Prometheus text exposition),
 ``/healthz`` and ``/stats`` next to the serving loop (0 = ephemeral
 port, printed); ``--trace-out FILE`` threads a request tracer through
-the engine/service and writes the capture as Chrome ``trace_event`` JSON
-(open in Perfetto, or render with ``python -m repro.obs.report``);
+the engine/service and writes its ring buffer as Chrome ``trace_event``
+JSON (open in Perfetto, or render with ``python -m repro.obs.report``).
+The serving path's same-thread spans also land in any ``jax.profiler``
+trace, with or without the flag;
 ``--obs-selfcheck`` scrapes the process's own sidecar over real HTTP and
 asserts the exposition parses and its counters reconcile with
 ``metrics()`` — the CI smoke gate.
@@ -270,8 +272,10 @@ def main(argv=None):
     ap.add_argument(
         "--trace-out", default=None, metavar="FILE",
         help="thread a request tracer through the serving path and write "
-             "the captured spans as Chrome trace_event JSON (view in "
-             "Perfetto or render with python -m repro.obs.report)",
+             "its ring buffer as Chrome trace_event JSON (view in "
+             "Perfetto or render with python -m repro.obs.report); the "
+             "http.*, engine.* and fetch.* spans reach any jax.profiler "
+             "trace without it",
     )
     ap.add_argument(
         "--obs-selfcheck", action="store_true",

@@ -3,14 +3,18 @@ profiling.
 
 Three layers, each usable on its own:
 
-  * :mod:`repro.obs.trace` — a lightweight thread-safe span tracer
-    (bounded ring buffer, injected monotonic clock, ~zero cost when
-    disabled) the serving stack threads through the whole query path:
-    ``submit -> queue_wait -> batch_assemble -> compile ->
-    device_dispatch -> demux``, plus child spans for semantic-cache
-    lookups, streaming-tier host page fetches, and mutable-index writes.
-    Exports Chrome ``trace_event`` JSON so a request's life is viewable
-    in Perfetto (https://ui.perfetto.dev).
+  * :mod:`repro.obs.trace` — the serving path's spans. The same-thread
+    phases (``http.decode``, ``engine.assemble``, ``engine.dispatch``,
+    ``engine.demux``, ``http.encode``, and ``fetch.page_fetch`` on the
+    host callback's thread) are ``jax.profiler.TraceAnnotation`` spans, so
+    they land in any ``jax.profiler`` trace beside the device's
+    operations, with nothing to switch on. An injected
+    :class:`~repro.obs.trace.Tracer` (bounded ring buffer, injected
+    monotonic clock, ~zero cost when disabled) records them as well,
+    plus the cross-thread ``submit``, ``queue_wait`` and ``request``
+    spans and child spans for semantic-cache lookups and mutable-index
+    writes, and exports Chrome ``trace_event`` JSON viewable in Perfetto
+    (https://ui.perfetto.dev).
   * :mod:`repro.obs.metrics` — a registry of named counters / gauges /
     histograms wrapping the existing ``EngineMetrics`` / ``CacheStats`` /
     compile-cache / fetch counters as sources, rendered as Prometheus
@@ -22,9 +26,8 @@ Three layers, each usable on its own:
     repro.obs.report`` renders a trace or profile into a human-readable
     phase breakdown.
 
-The serving layer never imports this package on its hot path — tracers
-and registries are injected (duck-typed), so observability stays an
-opt-in layer, not a dependency of the query loop.
+The serving layer imports :func:`~repro.obs.trace.phase` on its hot path;
+ring-buffer tracers and metric registries stay injected (duck-typed).
 """
 from repro.obs.metrics import (
     MetricsRegistry,
@@ -33,7 +36,7 @@ from repro.obs.metrics import (
     serve_registry,
 )
 from repro.obs.server import MetricsServer
-from repro.obs.trace import NULL_TRACER, Span, Tracer
+from repro.obs.trace import NULL_TRACER, Span, Tracer, phase
 
 __all__ = [
     "MetricsRegistry",
@@ -41,6 +44,7 @@ __all__ = [
     "NULL_TRACER",
     "Span",
     "Tracer",
+    "phase",
     "parse_prometheus_text",
     "sample_value",
     "serve_registry",

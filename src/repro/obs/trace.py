@@ -1,7 +1,8 @@
-"""Lightweight request tracer: bounded span ring buffer, Chrome export.
+"""Lightweight request tracer: bounded span ring buffer, Chrome export,
+and the serving path's spans on the profiler's clock.
 
 The serving stack emits one :class:`Span` per phase of a request's life
-(``submit``, ``queue_wait``, ``device_dispatch``, …) plus child spans for
+(``submit``, ``queue_wait``, ``engine.dispatch``, …) plus child spans for
 the host work hanging off a dispatch (semantic-cache lookup, streaming
 page fetches, mutable-index writes). Design constraints, in order:
 
@@ -21,6 +22,19 @@ page fetches, mutable-index writes). Design constraints, in order:
     trace, inject the same clock everywhere (the default everywhere is
     ``time.perf_counter``).
 
+**The profiler's view.** The phases that run on one thread —
+``http.decode``, ``http.encode``, ``engine.assemble``,
+``engine.dispatch``, ``engine.demux`` and ``fetch.page_fetch`` — are
+emitted through :func:`phase`, which opens a
+``jax.profiler.TraceAnnotation`` of the span's name. Whenever a
+``jax.profiler`` trace is recording, those spans land in its host plane,
+on the clock the device's operations are aligned to, with no tracer
+object, flag or environment variable; when none records, the annotation
+costs one ``TraceMe`` activity check. Only the ring buffer needs an
+injected, enabled :class:`Tracer`: :func:`phase` records the same span
+there, under the same name. The cross-thread spans (``submit``,
+``queue_wait``, ``request``) go to the ring buffer alone.
+
 Export: :meth:`Tracer.to_chrome_json` emits Chrome ``trace_event``
 format — complete (``ph: "X"``) events in microseconds with one tid per
 track name and thread-name metadata — loadable in Perfetto or
@@ -34,6 +48,8 @@ import json
 import threading
 import time
 from typing import Any, Callable, NamedTuple
+
+from jax.profiler import TraceAnnotation
 
 
 class Span(NamedTuple):
@@ -232,6 +248,60 @@ class Tracer:
             f"Tracer(spans={len(self)}, capacity={self._capacity}, "
             f"enabled={self.enabled})"
         )
+
+
+class _Phase:
+    """Context manager of :func:`phase`: the profiler annotation always,
+    the ring-buffer span when a tracer is on. ``t0``/``t1`` are the
+    ring-buffer timestamps (``None`` without a tracer)."""
+
+    __slots__ = ("_name", "_tracer", "_clock", "_cat", "_track", "args",
+                 "_ann", "t0", "t1")
+
+    def __init__(self, name, tracer, clock, cat, track, args):
+        self._name = name
+        self._tracer = tracer
+        self._clock = clock
+        self._cat = cat
+        self._track = track
+        self.args = args
+        self.t0 = self.t1 = None
+
+    def __enter__(self):
+        self._ann = TraceAnnotation(self._name, **self.args)
+        self._ann.__enter__()
+        if self._tracer is not None:
+            self.t0 = self._clock()
+        return self
+
+    def annotate(self, **args: Any) -> None:
+        """Add arguments known only once the phase has run."""
+        self.args.update(args)
+        self._ann.set_metadata(**args)
+
+    def __exit__(self, exc_type, exc, tb):
+        if self._tracer is not None:
+            self.t1 = self._clock()
+            self._tracer.add(self._name, self.t0, self.t1, cat=self._cat,
+                             track=self._track, args=self.args)
+        self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+
+def phase(name: str, tracer=None, *, clock: Callable[[], float] | None = None,
+          cat: str = "", track: str = "main", **args: Any) -> _Phase:
+    """The one emission point of a same-thread phase of the serving path.
+
+    Opens a ``jax.profiler.TraceAnnotation`` named ``name`` carrying
+    ``args`` as its metadata (it records whenever the profiler does);
+    with ``tracer`` injected and enabled, also records the span into the
+    tracer's ring buffer under the same name, stamped with ``clock``
+    (the tracer's own by default)."""
+    if tracer is not None and not tracer.enabled:
+        tracer = None
+    if clock is None and tracer is not None:
+        clock = tracer.now
+    return _Phase(name, tracer, clock, cat, track, args)
 
 
 # A process-wide disabled tracer for call sites that want an always-valid

@@ -63,6 +63,7 @@ import jax
 import numpy as np
 
 from repro.core.config import SearchParams
+from repro.obs.trace import phase
 from repro.serve.compile_cache import CompileCache, geometry_of, unshared_token
 
 DEFAULT_COLLECTION = "default"
@@ -87,7 +88,8 @@ class EngineMetrics(NamedTuple):
     snapshot instant. Monotonicity contract: the cumulative counters
     (``requests``, ``batches``, ``inserts``, ``deletes``,
     ``compactions``, ``early_exits``, ``compile_*``, ``pages_fetched``,
-    ``fetch_hits``, ``fetch_wall_s``, ``semantic_*``) never decrease
+    ``fetch_hits``, ``fetch_wall_s``, ``semantic_*``, ``hops_total``,
+    ``hop_page_reads``, ``hop_shared_reads``) never decrease
     across successive snapshots of one engine, and no counter can run
     ahead of the ``requests`` it belongs to within a snapshot — safe to
     export as Prometheus counters and ``rate()`` over. The remaining
@@ -138,6 +140,15 @@ class EngineMetrics(NamedTuple):
     semantic_misses: int = 0        # submits that fell through to a dispatch
     semantic_evictions: int = 0     # entries dropped by LRU or TTL
     semantic_invalidations: int = 0  # entries dropped by writes
+    # cumulative traversal counters over answered requests (the windowed
+    # gauges above cannot be differenced):
+    hops_total: int = 0        # SearchResult.hops summed
+    # pages scanned, cache hits included (ios + cache_hits), and of those
+    # the reads of a page that a lower-numbered lane of the same dispatch
+    # read at the same hop (SearchResult.shared_reads); counted only for
+    # backends that report shared_reads
+    hop_page_reads: int = 0
+    hop_shared_reads: int = 0
 
 
 class _Pending(NamedTuple):
@@ -235,6 +246,9 @@ class BatchingEngine:
             maxlen=latency_window
         )
         self._early_exits = 0
+        self._hops_total = 0
+        self._hop_page_reads = 0
+        self._hop_shared_reads = 0
         self._sheds = 0
         self._inserts = 0
         self._deletes = 0
@@ -346,8 +360,9 @@ class BatchingEngine:
             compact_fn = compact_fn or getattr(index, "compact", None)
             fetch_stats_fn = getattr(index, "fetch_stats", None)
             # streamed indexes: hang the engine's tracer on the host-side
-            # page fetcher so per-hop fetch callbacks show up as child
-            # spans of the dispatch that triggered them
+            # page fetcher so per-hop fetch callbacks land in its ring
+            # buffer beside the dispatch that triggered them (they reach a
+            # profiler trace without it)
             fetcher = getattr(index, "fetcher", None)
             if fetcher is not None and self._tracer is not None:
                 fetcher.tracer = self._tracer
@@ -857,55 +872,58 @@ class BatchingEngine:
         n = len(take)
         tr = self._tracer
         tracing = tr is not None and tr.enabled
-        t_take = self._clock() if tracing else 0.0
-        with self._lock:
-            col = self._collections.get(name)
-        if col is None:
-            # the collection was dropped between take and run (concurrent
-            # remove_collection): fail this group's waiters, not the engine
-            exc = RuntimeError(f"collection {name!r} was dropped")
+        with phase("engine.assemble", tr, clock=self._clock, cat="engine",
+                   track="engine", collection=name, batch_index=batch_index,
+                   n=n) as assemble:
             with self._lock:
-                self._dispatched_rows += self._batch_size
-                self._padded_rows += self._batch_size - n
-            for p in take:
-                p.future.set_exception(exc)
-            return
-        padded = np.zeros((self._batch_size, col.dim), self._dtype)
-        for i, p in enumerate(take):
-            padded[i] = p.query
-        # compiled-executable accounting: the cache key is the collection's
-        # GEOMETRY (not its name) plus everything else static in the jit
-        # signature — batch shape and the resolved runtime knobs — so two
-        # same-geometry collections register as one executable
-        try:
-            resolved = (
-                col.resolve_fn(k_bin, params)
-                if col.resolve_fn is not None
-                else (k_bin, params)
+                col = self._collections.get(name)
+            if col is None:
+                # the collection was dropped between take and run
+                # (concurrent remove_collection): fail this group's
+                # waiters, not the engine
+                exc = RuntimeError(f"collection {name!r} was dropped")
+                with self._lock:
+                    self._dispatched_rows += self._batch_size
+                    self._padded_rows += self._batch_size - n
+                for p in take:
+                    p.future.set_exception(exc)
+                return
+            padded = np.zeros((self._batch_size, col.dim), self._dtype)
+            for i, p in enumerate(take):
+                padded[i] = p.query
+            # compiled-executable accounting: the cache key is the
+            # collection's GEOMETRY (not its name) plus everything else
+            # static in the jit signature — batch shape and the resolved
+            # runtime knobs — so two same-geometry collections register as
+            # one executable
+            try:
+                resolved = (
+                    col.resolve_fn(k_bin, params)
+                    if col.resolve_fn is not None
+                    else (k_bin, params)
+                )
+            except Exception:
+                resolved = (k_bin, params)
+            warm = self._compile_cache.note(
+                col.geometry + (self._batch_size, resolved)
+                + ((("filter", flt),) if flt is not None else ())
             )
-        except Exception:
-            resolved = (k_bin, params)
-        warm = self._compile_cache.note(
-            col.geometry + (self._batch_size, resolved)
-            + ((("filter", flt),) if flt is not None else ())
-        )
         if tracing:
-            t_pad = self._clock()
-            tr.add("batch_assemble", t_take, t_pad, cat="engine",
-                   track="engine",
-                   args={"collection": name, "batch_index": batch_index,
-                         "n": n})
             for p in take:
-                tr.add("queue_wait", p.t_submit, t_take, cat="request",
+                tr.add("queue_wait", p.t_submit, assemble.t0, cat="request",
                        track=f"req-{p.rid}")
-        t_call = self._clock() if tracing else 0.0
         try:
-            out = (
-                col.search_fn(padded, k_bin, params, flt)
-                if col.accepts_filter
-                else col.search_fn(padded, k_bin, params)
-            )
-            out = jax.tree.map(np.asarray, out)
+            # a cold dispatch's wall includes trace+compile: its span says
+            # so in ``compiled``
+            with phase("engine.dispatch", tr, clock=self._clock,
+                       cat="engine", track="engine", collection=name,
+                       batch_index=batch_index, n=n, compiled=not warm):
+                out = (
+                    col.search_fn(padded, k_bin, params, flt)
+                    if col.accepts_filter
+                    else col.search_fn(padded, k_bin, params)
+                )
+                out = jax.tree.map(np.asarray, out)
         except Exception as e:
             # a backend failure must reach every waiter of THIS group
             # through its future — not hang them, not vanish into the timer
@@ -919,18 +937,25 @@ class BatchingEngine:
             return
 
         t_done = self._clock()
+        with phase("engine.demux", tr, clock=self._clock, cat="engine",
+                   track="engine", batch_index=batch_index, n=n) as demux:
+            latencies = self._demux(out, take, n, k_bin, batch_index,
+                                    resolved, t_done)
         if tracing:
-            # a cold dispatch's wall includes trace+compile: overlay a
-            # "compile" span on the dispatch that paid it
-            tr.add("device_dispatch", t_call, t_done, cat="engine",
-                   track="engine",
-                   args={"collection": name, "batch_index": batch_index,
-                         "n": n, "compiled": not warm})
-            if not warm:
-                tr.add("compile", t_call, t_done, cat="compile",
-                       track="engine", args={"collection": name})
+            for p, latency_ms in zip(take, latencies):
+                tr.add("request", p.t_submit, demux.t1, cat="request",
+                       track=f"req-{p.rid}",
+                       args={"latency_ms": latency_ms,
+                             "batch_index": batch_index})
+
+    def _demux(self, out, take: list[_Pending], n: int, k_bin: int,
+               batch_index: int, resolved, t_done: float) -> list[float]:
+        """Record the dispatch's counters and hand each request its row;
+        returns the requests' latencies (ms)."""
         ios = getattr(out, "ios", None)
         hops = getattr(out, "hops", None)
+        hits = getattr(out, "cache_hits", None)
+        shared = getattr(out, "shared_reads", None)
         latencies = [(t_done - p.t_submit) * 1e3 for p in take]
         with self._lock:
             self._dispatched_rows += self._batch_size
@@ -943,6 +968,7 @@ class BatchingEngine:
                 self._ios_win.extend(np.asarray(ios[:n]).ravel().tolist())
             if hops is not None:
                 self._hops_win.extend(np.asarray(hops[:n]).ravel().tolist())
+                self._hops_total += int(np.sum(hops[:n]))
                 if isinstance(resolved, SearchParams):
                     # requests that exited the hop loop before the resolved
                     # params' bound: adaptive early termination (or natural
@@ -950,6 +976,9 @@ class BatchingEngine:
                     self._early_exits += int(
                         np.sum(np.asarray(hops[:n]) < resolved.max_hops)
                     )
+            if shared is not None:          # a SearchResult with its trail
+                self._hop_page_reads += int(np.sum(ios[:n]) + np.sum(hits[:n]))
+                self._hop_shared_reads += int(np.sum(shared[:n]))
         for i, p in enumerate(take):
             row = jax.tree.map(lambda a: a[i], out)
             if p.k < k_bin:
@@ -968,15 +997,7 @@ class BatchingEngine:
                     batch_index=batch_index,
                 )
             )
-        if tracing:
-            t_end = self._clock()
-            tr.add("demux", t_done, t_end, cat="engine", track="engine",
-                   args={"batch_index": batch_index, "n": n})
-            for i, p in enumerate(take):
-                tr.add("request", p.t_submit, t_end, cat="request",
-                       track=f"req-{p.rid}",
-                       args={"latency_ms": latencies[i],
-                             "batch_index": batch_index})
+        return latencies
 
     # -------------------------------------------------------------- metrics
     def metrics(self) -> EngineMetrics:
@@ -1049,6 +1070,9 @@ class BatchingEngine:
                 ),
                 early_exits=self._early_exits,
                 sheds=self._sheds,
+                hops_total=self._hops_total,
+                hop_page_reads=self._hop_page_reads,
+                hop_shared_reads=self._hop_shared_reads,
             )
 
     def metrics_windows(self) -> dict:

@@ -27,9 +27,17 @@ dispatch. Every decision is visible in the exposition —
 ``pageann_http_requests_total{route=,code=}`` and
 ``pageann_http_rejected_total{reason=}`` ride the same registry as the
 engine series. No third-party dependencies.
+
+A ``/search`` request's decode (body read, ``json.loads``, the query
+matrix) is the ``http.decode`` span of :func:`repro.obs.trace.phase`, and
+the encode of every routed reply (the results and ``json.dumps``) is an
+``http.encode`` span, each tagged with the frontend's request id: they
+land in any ``jax.profiler`` trace, and in the ring buffer of the
+service's tracer when it has one.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import threading
 import time
@@ -38,8 +46,21 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 import numpy as np
 
 from repro.obs.server import PROMETHEUS_CONTENT_TYPE, _jsonable
+from repro.obs.trace import phase
 
 MAX_BODY_BYTES = 64 * 1024 * 1024
+
+
+def _plain(obj):
+    """``json.dumps``'s fallback: numpy arrays and scalars as plain lists
+    and numbers, so a reply's results are converted as they are encoded."""
+    if isinstance(obj, (np.ndarray, np.generic)):
+        return obj.tolist()
+    raise TypeError(f"{type(obj).__name__} is not JSON serializable")
+
+
+def _encode(doc: dict) -> bytes:
+    return json.dumps(doc, default=_plain).encode()
 
 
 class TokenBucket:
@@ -121,6 +142,8 @@ class HttpFrontend:
 
             registry = serve_registry(service)
         self._registry = registry
+        self._tracer = getattr(service, "tracer", None)
+        self._rids = itertools.count(1)   # request id of the http.* spans
         self._requests_total = registry.counter(
             "pageann_http_requests_total",
             "HTTP requests by route and status code",
@@ -148,8 +171,7 @@ class HttpFrontend:
 
             def _reply_json(self, code: int, doc: dict,
                             headers: dict | None = None) -> None:
-                self._reply(code, json.dumps(doc).encode(),
-                            "application/json", headers)
+                self._reply(code, _encode(doc), "application/json", headers)
 
             def _route(self) -> str:
                 return self.path.split("?", 1)[0]
@@ -168,9 +190,13 @@ class HttpFrontend:
                 return doc
 
             def _dispatch(self, fn) -> None:
+                """Reply with what ``fn(request_id)`` returns, or with the
+                error it raised; the encode is the request's
+                ``http.encode`` span."""
                 route = self._route()
+                rid = next(frontend._rids)
                 try:
-                    code, doc, headers = fn(route)
+                    code, doc, headers = fn(rid)
                 except _RequestError as e:
                     if e.reason is not None:
                         frontend._rejected_total.inc(
@@ -187,7 +213,10 @@ class HttpFrontend:
                 frontend._requests_total.inc(
                     labels={"route": route, "code": str(code)}
                 )
-                self._reply_json(code, doc, headers)
+                with phase("http.encode", frontend._tracer, cat="http",
+                           track="http", request=rid):
+                    body = _encode(doc)
+                self._reply(code, body, "application/json", headers)
 
             def do_GET(self):
                 route = self._route()
@@ -229,7 +258,7 @@ class HttpFrontend:
                 if fn is None:
                     self._reply_json(404, {"error": f"no route {route}"})
                     return
-                self._dispatch(lambda _route: fn(self._body()))
+                self._dispatch(lambda rid: fn(self._body, rid))
 
         self._httpd = ThreadingHTTPServer((host, port), Handler)
         self._httpd.daemon_threads = True
@@ -267,7 +296,7 @@ class HttpFrontend:
         return name
 
     # --------------------------------------------------------- handlers
-    def _handle_collections(self, _route: str):
+    def _handle_collections(self, _rid: int):
         svc = self._service
         out = []
         for name in sorted(svc.list_collections()):
@@ -278,20 +307,23 @@ class HttpFrontend:
                 continue  # dropped between list and lookup
         return 200, {"collections": out}, {}
 
-    def _handle_search(self, doc: dict):
-        name = self._collection_of(doc)
-        if "queries" in doc:
-            queries = doc["queries"]
-            single = False
-        elif "query" in doc:
-            queries = [doc["query"]]
-            single = True
-        else:
-            raise _RequestError(400, "missing 'query' or 'queries'")
-        try:
-            q = np.asarray(queries, np.float32)
-        except (TypeError, ValueError) as e:
-            raise _RequestError(400, f"bad query payload: {e}")
+    def _handle_search(self, read_body, rid: int):
+        with phase("http.decode", self._tracer, cat="http", track="http",
+                   request=rid):
+            doc = read_body()
+            name = self._collection_of(doc)
+            if "queries" in doc:
+                queries = doc["queries"]
+                single = False
+            elif "query" in doc:
+                queries = [doc["query"]]
+                single = True
+            else:
+                raise _RequestError(400, "missing 'query' or 'queries'")
+            try:
+                q = np.asarray(queries, np.float32)
+            except (TypeError, ValueError) as e:
+                raise _RequestError(400, f"bad query payload: {e}")
         if q.ndim != 2 or q.shape[0] == 0:
             raise _RequestError(
                 400, f"queries must be a non-empty (Q, d) matrix, "
@@ -324,11 +356,10 @@ class HttpFrontend:
                     results.append(None)
                     continue
                 res = rr.result
-                ids = np.asarray(res.ids)
-                dists = np.asarray(res.dists)
+                # arrays: the encode in _dispatch makes them lists
                 results.append({
-                    "ids": ids.reshape(-1).tolist(),
-                    "dists": dists.reshape(-1).tolist(),
+                    "ids": np.asarray(res.ids).reshape(-1),
+                    "dists": np.asarray(res.dists).reshape(-1),
                     "cached": bool(rr.cached),
                 })
             if shed == len(futs):
@@ -347,7 +378,8 @@ class HttpFrontend:
         finally:
             release()
 
-    def _handle_insert(self, doc: dict):
+    def _handle_insert(self, read_body, _rid: int):
+        doc = read_body()
         name = self._collection_of(doc)
         vectors = doc.get("vectors")
         if vectors is None:
@@ -370,7 +402,8 @@ class HttpFrontend:
         finally:
             release()
 
-    def _handle_delete(self, doc: dict):
+    def _handle_delete(self, read_body, _rid: int):
+        doc = read_body()
         name = self._collection_of(doc)
         ids = doc.get("ids")
         if ids is None:

@@ -147,6 +147,12 @@ class VectorService:
     def __exit__(self, exc_type, exc, tb) -> None:
         self.close()
 
+    @property
+    def tracer(self):
+        """The injected span tracer, or None (the HTTP frontend records
+        its spans there too)."""
+        return self._tracer
+
     def close(self) -> None:
         """Flush and shut down the shared engine. Idempotent."""
         with self._lock:

@@ -13,6 +13,9 @@ import): only one process at a time may load the TPU library, and the
 fixture skips where it cannot be described.
 """
 import os
+import re
+import sys
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +27,9 @@ from repro.kernels import hamming as hamming_k
 from repro.kernels import l2dist as l2_k
 from repro.kernels import page_scan as ps_k
 from repro.kernels import pq_adc as adc_k
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
+from bench import readers  # noqa: E402
 
 # (dim, page capacity, page degree, PQ subspaces): the HYBRID geometry
 # ``PageANNConfig.resolve_capacity`` gives a 4 KB page at each dim
@@ -145,3 +151,10 @@ def test_batch_search_executable_compiles(one_chip, monkeypatch):
         # the trace took the TPU branch: no later CPU call may reuse it
         jax.clear_caches()
     assert "tpu_custom_call" in hlo
+    # a profiler trace knows each op by this scope: the page-scan kernel
+    # by the benchmark's pattern, the rest by the hop stage that ran it
+    scopes = re.findall(r'op_name="([^"]*)"', hlo)
+    assert any(re.search(readers.PAGE_SCAN, s) for s in scopes)
+    assert set(re.findall(r"hop_[a-z_]+", " ".join(scopes))) == {
+        "hop_select", "hop_scan", "hop_nbr_adc", "hop_cand_probe",
+        "hop_dedupe", "hop_merge"}
