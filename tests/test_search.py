@@ -97,6 +97,70 @@ def test_optimized_loop_matches_seed_search(dataset, mode_index):
         ), i
 
 
+@pytest.mark.parametrize("beam", [16, 96, 128])
+def test_beam_membership_compare_matches_sorted_probe(dataset, hybrid_index,
+                                                      beam):
+    """The hop's beam-membership test is a dense compare: it equals
+    ``np.isin`` over beams and neighbour lists holding PAD, duplicates and
+    absent ids, and ``score_page_batch``'s estimates are bit-identical to
+    the sorted ``searchsorted`` probe's masking of the same hop."""
+    from repro.core import pq as pq_mod
+    from repro.core import search as search_mod
+
+    idx = hybrid_index
+    data, cap = idx.data, idx.store.capacity
+    nbr_ids = np.asarray(data.nbr_ids)
+    num_pages, b = nbr_ids.shape[0], 5
+    rng = np.random.default_rng(beam)
+    q = jnp.asarray(dataset[1][0], jnp.float32)
+    disk_lut = pq_mod.pq_lut(q, data.disk_codebooks)
+    mem_lut = pq_mod.pq_lut(q, data.mem_codebooks)
+    for _ in range(8):
+        batch = rng.choice(num_pages, b, replace=False).astype(np.int32)
+        batch[rng.integers(b)] = search_mod.PAD       # an unscheduled lane
+        nids = nbr_ids[np.maximum(batch, 0)].reshape(-1)
+        # the beam: some of this hop's neighbours (duplicated), ids absent
+        # from the hop, and PAD slots
+        cand = np.concatenate([
+            rng.choice(nids, beam // 2),
+            rng.integers(0, num_pages * cap, beam // 4),
+            np.full(beam - beam // 2 - beam // 4, search_mod.PAD),
+        ]).astype(np.int32)
+        rng.shuffle(cand)
+        probe = np.append(nids, search_mod.PAD).astype(np.int32)
+        got = search_mod.in_beam(jnp.asarray(probe), jnp.asarray(cand))
+        np.testing.assert_array_equal(np.asarray(got), np.isin(probe, cand))
+
+        page_vis = rng.random(num_pages) < 0.1
+        state = search_mod.BeamState(
+            cand_ids=jnp.asarray(cand),
+            cand_d=jnp.zeros((beam,), jnp.float32),
+            cand_vis=jnp.zeros((beam,), bool),
+            page_vis=jnp.asarray(page_vis),
+            res_ids=jnp.full((10,), search_mod.PAD, jnp.int32),
+            res_d=jnp.full((10,), jnp.inf, jnp.float32),
+            io=jnp.int32(0), cache_hits=jnp.int32(0), hops=jnp.int32(0),
+        )
+
+        def est_of(state):
+            out = search_mod.score_page_batch(
+                q, data, jnp.asarray(batch), state, disk_lut, mem_lut,
+                capacity=cap, mode=idx.cfg.memory_mode.value)
+            np.testing.assert_array_equal(np.asarray(out[2]), nids)
+            return np.asarray(out[3])
+
+        # every mask but the beam's (an all-PAD beam only matches PAD
+        # neighbours, which the validity mask drops anyway), then the
+        # sorted probe's mask on top
+        base = est_of(state._replace(
+            cand_ids=jnp.full((beam,), search_mod.PAD, jnp.int32)))
+        s = np.sort(cand)
+        pos = np.minimum(np.searchsorted(s, nids), beam - 1)
+        want = np.where(s[pos] == nids, np.float32(np.inf), base)
+        np.testing.assert_array_equal(est_of(state), want)
+        assert np.isfinite(want).any() and np.isinf(want).any()
+
+
 def test_mem_all_packs_more_vectors_per_page(dataset):
     x, _, _ = dataset
     disk = PageANNIndex.build(x, _cfg(memory_mode=MemoryMode.DISK_ONLY))

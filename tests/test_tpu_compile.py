@@ -158,3 +158,6 @@ def test_batch_search_executable_compiles(one_chip, monkeypatch):
     assert set(re.findall(r"hop_[a-z_]+", " ".join(scopes))) == {
         "hop_select", "hop_scan", "hop_nbr_adc", "hop_cand_probe",
         "hop_dedupe", "hop_merge"}
+    # the beam-membership probe is one dense compare: no serial loop (a
+    # searchsorted binary search lowers to a while loop of gathers)
+    assert not [s for s in scopes if re.search(r"hop_cand_probe/.*while", s)]
