@@ -1,5 +1,6 @@
 """The comparison that decides ``correct``: served answers against the
-exact reference.
+exact reference, which the configuration's kind gives (``truth`` and
+``distances``; bench/spec.py).
 
 Every answer of every request sent in the window is compared, once the
 window has closed. Four numbers, each with its limit; a run is correct
@@ -13,11 +14,12 @@ when none is above its limit:
   miss_at_<k>  1 - mean recall@k against the exact k nearest. The limit
                is the configuration's own: 1 - its stated recall floor.
   dist_gap     the widest gap between a served distance and the exact
-               float32 squared distance of the id served with it, over
-               the query's exact k-th distance. The page scan computes
-               member distances exactly in float32, so sound runs read
-               rounding; the limit lies between the readings of sound
-               runs and of the bfloat16 control (PERF.md, section 2).
+               distance of the id served with it (float32 squared L2 for
+               ``vectors_l2``), over the query's exact k-th distance.
+               The page scan computes member distances exactly in
+               float32, so sound runs read rounding; the limit lies
+               between the readings of sound runs and of the bfloat16
+               control (PERF.md, section 2).
 
 Under a memory budget a fifth number holds the configuration's guarantee
 that the budget moves pages and never answers:
@@ -32,20 +34,18 @@ from __future__ import annotations
 
 import numpy as np
 
-from bench import reference
-
 DIST_GAP_LIMIT = 1e-4
 
 
-def compare(x: np.ndarray, pool: np.ndarray, qidx: np.ndarray,
-            ids: np.ndarray, dists: np.ndarray, *, unanswered: int,
-            recall_floor: float, resident_ids: np.ndarray | None = None
-            ) -> dict:
+def compare(kind, data, qidx: np.ndarray, ids: np.ndarray,
+            dists: np.ndarray, *, unanswered: int, recall_floor: float,
+            resident_ids: np.ndarray | None = None) -> dict:
     """Judge the answers ``ids``/``dists`` (A, k) served for the pool
-    queries ``qidx`` (A,); ``resident_ids`` (A, k) is a memory budget's
-    resident witness. Returns {name: {"value", "limit"}}."""
+    queries ``qidx`` (A,) of ``data`` (``corpus.Data``) by ``kind``'s
+    reference; ``resident_ids`` (A, k) is a memory budget's resident
+    witness. Returns {name: {"value", "limit"}}."""
     k = ids.shape[1]
-    n = len(x)
+    n = len(data.corpus["vectors"])
     ids = np.asarray(ids, np.int64)
     dists = np.asarray(dists, np.float32)
     in_range = ((ids >= 0) & (ids < n)).all(axis=1)
@@ -56,13 +56,13 @@ def compare(x: np.ndarray, pool: np.ndarray, qidx: np.ndarray,
     good = in_range & distinct & finite & ascending
 
     asked = np.unique(qidx)
-    truth_ids, truth_d = reference.exact_knn(x, pool[asked], k)
+    truth_ids, truth_d = kind.truth(data, asked, k)
     row = np.searchsorted(asked, qidx)
     hits = (ids[:, :, None] == truth_ids[row][:, None, :]).any(axis=2)
     recall = float(hits.mean()) if len(ids) else 0.0
 
     safe = np.where(in_range[:, None], ids, 0)
-    exact = reference.sq_dists(x, pool[qidx], safe)
+    exact = kind.distances(data, qidx, safe)
     scale = np.maximum(truth_d[row][:, -1:], np.float32(1e-12))
     gap = np.abs(dists.astype(np.float64) - exact) / scale
     # ids out of range and non-finite distances are bad_answers' to count

@@ -4,9 +4,9 @@ by the same comparison as a run.
     python bench/control.py --workload <cell>
 
 It makes the cell's corpus and query pool, answers every pool query (all
-that any seed's run asks, in whatever order) with
-``reference.control_knn`` (the corpus stored in bfloat16, scored in
-float32) in the program's place, and prints the numbers
+that any seed's run asks, in whatever order) with its kind's ``control``
+(for ``vectors_l2``, ``reference.control_knn``: the corpus stored in
+bfloat16, scored in float32) in the program's place, and prints the numbers
 ``check.compare`` reads as one JSON line. The control has to come out as
 not correct; its ``dist_gap`` is the upper reading the limit is set
 below (PERF.md, section 2). The benchmark's own runs never run it.
@@ -22,16 +22,16 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
 
 import numpy as np  # noqa: E402
 
-from bench import check, corpus, reference, spec  # noqa: E402
+from bench import check, spec  # noqa: E402
 
 
 def readings(cell) -> dict:
-    cfg, mix = cell.config, cell.traffic
-    x = corpus.make_corpus(cfg)
-    pool = corpus.make_pool(x, mix)
-    ids, dists = reference.control_knn(x, pool, mix["k"])
-    checks = check.compare(x, pool, np.arange(len(pool)), ids, dists,
-                           unanswered=0, recall_floor=cfg["recall_floor"])
+    cfg, mix, kind = cell.config, cell.traffic, cell.kind
+    data = kind.data(cfg, mix)
+    every = np.arange(len(data.pool["queries"]))
+    ids, dists = kind.control(data, every, mix["k"])
+    checks = check.compare(kind, data, every, ids, dists, unanswered=0,
+                           recall_floor=cfg["recall_floor"])
     return {"correct": check.passed(checks),
             "checks": {k: c["value"] for k, c in checks.items()}}
 

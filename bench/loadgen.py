@@ -2,10 +2,12 @@
 
 It never imports JAX, so its threads take neither the server's
 interpreter lock nor the chip. The parent writes one JSON line of
-settings and then the query pool (``np.save`` format) to its standard
-input; the generator sends until the window's end, waits for every
-answer, and writes what it saw to standard output as one ``np.savez``
-archive:
+settings, which name the file of the configuration's kind
+(``kind_file``), and then the query pool's arrays (``np.savez`` format)
+to its standard input; the kind makes each request's body from them
+(bench/spec.py). The generator sends until the window's end, waits for
+every answer, and writes what it saw to standard output as one
+``np.savez`` archive:
 
   t_due, t_send, t_recv   per request, host monotonic seconds (the clock
                           the parent's window is on). A closed loop's
@@ -39,7 +41,7 @@ from pathlib import Path
 import numpy as np
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
-from bench import corpus  # noqa: E402
+from bench import corpus, spec  # noqa: E402
 
 
 def _send(conn_args, body: bytes, timeout: float):
@@ -83,23 +85,19 @@ def _answers(doc, n: int, k: int):
     return ids, dists, ok, True
 
 
-def encoded(pool: np.ndarray) -> list[str]:
-    """Each pool query as JSON, encoded once: a body is then a join."""
-    return [json.dumps(row) for row in pool.tolist()]
+def bodies(kind, s: dict, pool: dict):
+    """(the number of pool queries, a function from pool indices to the
+    bytes of the request that asks them): the kind encodes each pool query
+    once, so a body is then a join."""
+    frags = kind.encoded(pool)
+    return len(frags), lambda qidx: kind.body(s, frags, qidx)
 
 
-def body(s: dict, frags: list[str], qidx: np.ndarray) -> bytes:
-    head = f'{{"collection": {json.dumps(s["collection"])}, "k": {s["k"]}, '
-    if len(qidx) == 1:
-        return (head + f'"query": {frags[qidx[0]]}}}').encode()
-    return (head + '"queries": [' + ", ".join(frags[i] for i in qidx)
-            + "]}").encode()
-
-
-def request(s: dict, frags: list[str], qidx: np.ndarray, t_due: float,
+def request(s: dict, body, qidx: np.ndarray, t_due: float,
             rows: list) -> None:
-    """Send one request and append what came back to ``rows``."""
-    data = body(s, frags, qidx)
+    """Send the request ``body`` makes of ``qidx`` and append what came
+    back to ``rows``."""
+    data = body(qidx)
     t_send = time.monotonic()
     status, out = _send(s["conn"], data, s["timeout_s"])
     t_recv = time.monotonic()
@@ -112,20 +110,20 @@ def request(s: dict, frags: list[str], qidx: np.ndarray, t_due: float,
                  dists, ok))
 
 
-def _closed(s: dict, frags: list[str]) -> list[list]:
+def _closed(s: dict, body, n_pool: int) -> list[list]:
     qpr = s["queries_per_request"]
     # enough queries for any sender at any speed this window allows
     cap = int(s["t_end"] - s["t_start"] + 1) * 20000
 
     def sender(c: int, rows: list) -> None:
-        order = corpus.query_order(s["seed"], c, len(frags), cap)
+        order = corpus.query_order(s["seed"], c, n_pool, cap)
         pos = 0
         while time.monotonic() < s["t_start"]:
             time.sleep(0.001)
         while time.monotonic() < s["t_end"]:
             idx = order[pos:pos + qpr]
             pos += qpr
-            request(s, frags, idx, time.monotonic(), rows)
+            request(s, body, idx, time.monotonic(), rows)
 
     recs = [[] for _ in range(s["clients"])]
     threads = [threading.Thread(target=sender, args=(c, r), daemon=True)
@@ -137,12 +135,12 @@ def _closed(s: dict, frags: list[str]) -> list[list]:
     return recs
 
 
-def _open(s: dict, frags: list[str]) -> list[list]:
+def _open(s: dict, body, n_pool: int) -> list[list]:
     qpr = s["queries_per_request"]
     offsets = corpus.arrival_offsets(
         s["seed"], s["rate"], s["t_end"] - s["t_start"]
     )
-    order = corpus.query_order(s["seed"], 0, len(frags), len(offsets) * qpr)
+    order = corpus.query_order(s["seed"], 0, n_pool, len(offsets) * qpr)
     work: queue.Queue = queue.Queue()
 
     def sender(rows: list) -> None:
@@ -151,7 +149,7 @@ def _open(s: dict, frags: list[str]) -> list[list]:
             if item is None:
                 return
             i, t_due = item
-            request(s, frags, order[i * qpr:(i + 1) * qpr], t_due, rows)
+            request(s, body, order[i * qpr:(i + 1) * qpr], t_due, rows)
 
     recs = [[] for _ in range(s["max_outstanding"])]
     threads = [threading.Thread(target=sender, args=(r,), daemon=True)
@@ -176,10 +174,11 @@ def conn_args(url: str) -> tuple:
     return u.hostname, u.port
 
 
-def run(s: dict, pool: np.ndarray) -> bytes:
+def run(s: dict, pool: dict) -> bytes:
     """Send the mix and return the npz archive of what came back."""
     s = dict(s, conn=conn_args(s["url"]))
-    recs = (_closed if s["loop"] == "closed" else _open)(s, encoded(pool))
+    n_pool, body = bodies(spec.load_kind(s["kind_file"]), s, pool)
+    recs = (_closed if s["loop"] == "closed" else _open)(s, body, n_pool)
     rows = sorted((r for rec in recs for r in rec), key=lambda r: r[1])
     qpr, k = s["queries_per_request"], s["k"]
 
@@ -202,7 +201,8 @@ def run(s: dict, pool: np.ndarray) -> bytes:
 
 def main() -> int:
     settings = json.loads(sys.stdin.buffer.readline())
-    pool = np.load(io.BytesIO(sys.stdin.buffer.read()), allow_pickle=False)
+    pool = dict(np.load(io.BytesIO(sys.stdin.buffer.read()),
+                        allow_pickle=False))
     sys.stdout.buffer.write(run(settings, pool))
     sys.stdout.buffer.flush()
     return 0

@@ -3,28 +3,33 @@
     python bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The data set is fixed and ``--seed`` draws the order of the requests
-(corpus.py):
+(corpus.py). What depends on the deployment's shape comes from the
+configuration's kind, bench/kinds/<kind>.py (bench/spec.py says what it
+gives); every step and check is this file's, loadgen.py's and check.py's:
 
   1. device  JAX must find a TPU and the chips the cell asks for; else the
              run exits 3 and prints no result.
-  2. data    the configuration's corpus and the mix's query pool.
-  3. index   the index the program builds from the corpus
-             (``PageANNIndex.build``), saved (``PageANNIndex.save``) in
-             bench/dbcache/<digest>/, keyed by the corpus and index
-             settings, the program's code and the device kind: the first
-             run in a checkout builds it, later runs load it as a
+  2. data    the kind's corpus and query pool, from the configuration and
+             the mix.
+  3. index   the index the kind builds from the corpus with the program
+             (``PageANNIndex.build`` for ``vectors_l2``), saved in
+             bench/dbcache/<digest>/, keyed by the kind's digest inputs,
+             the kind's and the program's code and the device kind: the
+             first run in a checkout builds it, later runs load it as a
              restarted server loads its saved index.
-  4. serve   ``VectorService.attach`` of the saved index, with the
-             configuration's search settings and memory budget, behind
-             ``HttpFrontend``: the served path.
-  5. warm    requests of the mix's own shape until one compiles nothing.
+  4. serve   the kind attaches the saved index to ``VectorService``, under
+             the configuration's memory budget, behind ``HttpFrontend``:
+             the served path.
+  5. warm    requests the kind makes of the first pool queries of the
+             seed's own order, until one compiles nothing.
   6. window  the load generator (loadgen.py, a child process that never
              imports JAX) sends the mix; the window is ``--seconds`` long
              and starts after the mix's ramp. With ``--trace 1`` the
-             profiler records a few seconds in its middle.
+             profiler records a few seconds in its middle; the engine's
+             counters are read at the window's edges all the same.
   7. judge   once the window has closed, the peak memory is read and the
              server closed, every answer of a request sent in the window
-             is compared with the exact reference (check.py).
+             is compared with the kind's exact reference (check.py).
 
 The last line of standard output is the result, one JSON object; the
 last lines of standard error are the numbers compared, each beside its
@@ -109,35 +114,26 @@ def device_info(chips: int, rehearsal: bool) -> dict:
     return dev
 
 
-def db_digest(cfg: dict, kind: str) -> str:
+def db_digest(kind, cfg: dict, device_kind: str) -> str:
     """Key of a built index: what it was built from and by."""
     h = hashlib.sha256(json.dumps({
-        "corpus": {k: cfg[k] for k in
-                   ("num_vectors", "dim", "clusters", "cluster_scale")},
-        "index": cfg["index"], "device_kind": kind,
+        "data": kind.digest_inputs(cfg), "device_kind": device_kind,
     }, sort_keys=True).encode())
     code = sorted((ROOT / "src" / "repro").rglob("*.py"))
     for p in code + [ROOT / "bench" / "corpus.py"]:
         h.update(str(p.relative_to(ROOT)).encode())
         h.update(p.read_bytes())
+    h.update(Path(kind.__file__).read_bytes())
     return h.hexdigest()[:24]
 
 
-def build_index(cfg: dict, x: np.ndarray, path: Path) -> float:
-    """Build the index of ``x`` and save it at ``path``; returns seconds."""
-    from repro.core import MemoryMode, PageANNConfig, PageANNIndex
-
-    ix = cfg["index"]
+def build_index(kind, cfg: dict, data, path: Path) -> float:
+    """Build the kind's index of ``data`` and save it at ``path``; returns
+    seconds."""
     t = time.monotonic()
-    index = PageANNIndex.build(x, PageANNConfig(
-        dim=cfg["dim"], graph_degree=ix["graph_degree"],
-        build_beam=ix["build_beam"], pq_subspaces=ix["pq_subspaces"],
-        page_bytes=ix["page_bytes"], lsh_sample=ix["lsh_sample"],
-        seed=ix["seed"], memory_mode=MemoryMode(ix["memory_mode"]),
-    ))
     tmp = path.with_name(path.name + ".tmp")
     shutil.rmtree(tmp, ignore_errors=True)
-    index.save(str(tmp))
+    kind.build(cfg, data, str(tmp))
     os.replace(tmp, path)
     return time.monotonic() - t
 
@@ -152,20 +148,23 @@ def sleep_until(t: float) -> None:
         time.sleep(wait)
 
 
-def warm_up(url: str, mix: dict, pool: np.ndarray, compiles: Compiles,
-            k: int) -> int:
-    """Send requests of the mix's shape until one compiles nothing;
-    returns how many were sent."""
+def warm_up(url: str, kind, data, mix: dict, seed: int,
+            compiles: Compiles, k: int) -> int:
+    """Send the requests the kind makes of the first pool queries of the
+    seed's own order, a request of the mix's size each, until one compiles
+    nothing; returns how many were sent."""
     from bench import loadgen
 
     s = {"collection": COLLECTION, "k": k, "timeout_s": CLIENT_TIMEOUT_S,
          "conn": loadgen.conn_args(url)}
-    qidx = np.arange(mix["queries_per_request"]) % len(pool)
-    frags = loadgen.encoded(pool)
+    qpr = mix["queries_per_request"]
+    n_pool, body = loadgen.bodies(kind, s, data.pool)
+    order = corpus.query_order(seed, 0, n_pool, WARM_TRIES * qpr)
     for tries in range(1, WARM_TRIES + 1):
         before = compiles.count
         rows: list = []
-        loadgen.request(s, frags, qidx, time.monotonic(), rows)
+        loadgen.request(s, body, order[(tries - 1) * qpr:tries * qpr],
+                        time.monotonic(), rows)
         if rows[0][3] != 200:
             raise RuntimeError(f"warm-up request -> HTTP {rows[0][3]}")
         if compiles.count == before:
@@ -185,7 +184,7 @@ class Window:
     loadgen: dict | None = None
 
 
-def drive(url: str, mix: dict, pool: np.ndarray, seed: int, seconds: float,
+def drive(url: str, kind, mix: dict, pool: dict, seed: int, seconds: float,
           svc, compiles: Compiles, trace: bool, k: int) -> Window:
     """Run the load generator through one window; snapshot the engine's
     counters at its edges and trace its middle."""
@@ -195,9 +194,10 @@ def drive(url: str, mix: dict, pool: np.ndarray, seed: int, seconds: float,
     settings = dict(
         mix, url=url, collection=COLLECTION, k=k, seed=seed,
         t_start=t_start, t0=t0, t_end=t_end, timeout_s=CLIENT_TIMEOUT_S,
+        kind_file=kind.__file__,
     )
     buf = io.BytesIO()
-    np.save(buf, pool)
+    np.savez(buf, **pool)
     payload = json.dumps(settings).encode() + b"\n" + buf.getvalue()
     child = subprocess.Popen(
         [sys.executable, str(ROOT / "bench" / "loadgen.py")],
@@ -210,21 +210,28 @@ def drive(url: str, mix: dict, pool: np.ndarray, seed: int, seconds: float,
 
     talker = threading.Thread(target=talk, daemon=True)
     talker.start()
+    # the counters are read at the window's end on a timer: with a trace,
+    # stopping the profiler takes many seconds past that end
+    edge: dict = {}
+    at_end = threading.Timer(
+        0, lambda: edge.update(c=compiles.count, m=snapshot(svc),
+                               s=time.monotonic()))
     try:
         sleep_until(t0)
         c0, m0, s0 = compiles.count, snapshot(svc), time.monotonic()
-        traced_s = tcount = None
+        at_end.interval = t_end - s0
+        at_end.start()
+        traced_s = tcount = traced = None
         if trace:
             traced_s, tcount = _trace(svc, (t0 + t_end - TRACE_S) / 2)
-        sleep_until(t_end)
-        c1, m1, s1 = compiles.count, snapshot(svc), time.monotonic()
+        at_end.join()
         talker.join(t_end - t_start + CLIENT_TIMEOUT_S + 60)
         # read once the window has closed: the reading takes host time
-        traced = None
         if trace:
             traced = trace_reduce.read(str(TRACE_DIR), traced_s, SPAN)
             shutil.rmtree(TRACE_DIR, ignore_errors=True)
     finally:
+        at_end.cancel()
         if child.poll() is None:
             child.kill()
         child.wait()
@@ -234,8 +241,8 @@ def drive(url: str, mix: dict, pool: np.ndarray, seed: int, seconds: float,
             f"{got.get('err', b'')[-2000:].decode(errors='replace')}"
         )
     lg = dict(np.load(io.BytesIO(got["out"]), allow_pickle=False))
-    return Window(t0, t_end, {"start": m0, "end": m1}, s1 - s0, c1 - c0,
-                  traced, tcount, lg)
+    return Window(t0, t_end, {"start": m0, "end": edge["m"]}, edge["s"] - s0,
+                  edge["c"] - c0, traced, tcount, lg)
 
 
 def _trace(svc, at: float):
@@ -295,7 +302,7 @@ def end_to_end(cell, view: dict, recall: float, setup_s: float) -> dict:
 def per_layer(cell, record: dict) -> dict:
     out = {}
     for m in cell.per_layer:
-        v = spec.metric_reader(m["name"])(record)
+        v = spec.metric_reader(m["name"], cell.root)(record)
         if v is not None:
             out[m["name"]] = {"value": float(v), "unit": m["unit"]}
     return out
@@ -322,21 +329,22 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
             raise KeyError(f"no peaks for device kind {dev['kind']!r}")
         peaks = table[dev["kind"]]
 
-    x = corpus.make_corpus(cfg)
-    pool = corpus.make_pool(x, mix)
-    db = (db_cache or DB_CACHE) / db_digest(cfg, dev["kind"])
+    kind = cell.kind
+    data = kind.data(cfg, mix)
+    db = (db_cache or DB_CACHE) / db_digest(kind, cfg, dev["kind"])
     # the build makes the data set into an index once per checkout: the
     # first run pays it, later runs load what it saved, so it is logged on
     # its own line and left out of setup_s
     build_s = 0.0
     if not (db / "manifest.json").exists():
-        build_s = build_index(cfg, x, db)
-        log(f"build {build_s:.3f}s (build+save, N = {len(x)})")
+        build_s = build_index(kind, cfg, data, db)
+        log(f"build {build_s:.3f}s (build+save, "
+            f"N = {len(data.corpus['vectors'])})")
     else:
         log("build 0s (index from the cache)")
     paid = []
 
-    from repro.core import MemoryBudget, SearchParams
+    from repro.core import MemoryBudget
     from repro.serve import HttpFrontend, VectorService
 
     compiles = Compiles()
@@ -346,23 +354,20 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
         frac = cfg["memory_budget_fraction"]
         svc = VectorService(batch_size=cfg["serving"]["batch_size"])
         try:
-            svc.attach(
-                COLLECTION, str(db), params=SearchParams(k=k, **cfg["search"]),
-                memory_budget=MemoryBudget(fraction=frac) if frac else None,
-            )
+            kind.attach(svc, COLLECTION, str(db), cfg, k,
+                        MemoryBudget(fraction=frac) if frac else None)
             with HttpFrontend(
                 svc, port=0, max_inflight=cfg["serving"]["max_inflight"]
             ) as fe:
                 paid.append(f"load {time.monotonic() - t:.3f}s")
                 t = time.monotonic()
-                n_warm = warm_up(fe.url, mix, pool, compiles, k)
+                n_warm = warm_up(fe.url, kind, data, mix, seed, compiles, k)
                 paid.append(f"warm-up {time.monotonic() - t:.3f}s "
                             f"({n_warm} requests, {compiles.count} "
                             f"executables)")
-                recs = svc.index_of(COLLECTION).data.page_recs
-                record_bytes = int(np.prod(recs.shape[1:])) * recs.dtype.itemsize
-                w = drive(fe.url, mix, pool, seed, seconds, svc, compiles,
-                          trace and not rehearsal, k)
+                record_bytes = kind.record_bytes(svc.index_of(COLLECTION))
+                w = drive(fe.url, kind, mix, data.pool, seed, seconds, svc,
+                          compiles, trace and not rehearsal, k)
             peak = dev_memory_peak()
         finally:
             svc.close()
@@ -375,16 +380,17 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
     qpr = mix["queries_per_request"]
     view = client_view(w, qpr, w.t_end - w.t0)
     late = view["late_ms"]
-    log(f"window {w.t_end - w.t0:.3f}s: {view['attempted']} queries "
-        f"attempted, {view['failed']} failed; generator late by "
+    log(f"window {w.t_end - w.t0:.3f}s (counters over {w.counter_s:.3f}s): "
+        f"{view['attempted']} queries attempted, {view['failed']} failed; "
+        f"generator late by "
         f"{float(np.max(late)) if len(late) else 0.0:.3f} ms at most")
     resident = None
     if frac:
         t = time.monotonic()
-        resident = resident_answers(db, cfg, pool, view["qidx"], k)
+        resident = resident_answers(kind, db, cfg, data, view["qidx"], k)
         log(f"resident witness {time.monotonic() - t:.3f}s")
     checks = check.compare(
-        x, pool, view["qidx"], view["ids"], view["dists"],
+        kind, data, view["qidx"], view["ids"], view["dists"],
         unanswered=view["failed"], recall_floor=cfg["recall_floor"],
         resident_ids=resident,
     )
@@ -414,20 +420,18 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
     return result
 
 
-def resident_answers(db: Path, cfg: dict, pool: np.ndarray,
-                     qidx: np.ndarray, k: int) -> np.ndarray:
+def resident_answers(kind, db: Path, cfg: dict, data, qidx: np.ndarray,
+                     k: int) -> np.ndarray:
     """The witness of a memory budget's guarantee: the same saved index
-    attached with every page in HBM, asked the pool queries ``qidx`` in
-    full batches through the same engine. Returns (A, k) ids, row i the
-    answer to ``qidx[i]``."""
-    from repro.core import SearchParams
+    attached by the kind with every page in HBM, asked the pool queries
+    ``qidx`` in full batches through the same engine. Returns (A, k) ids,
+    row i the answer to ``qidx[i]``."""
     from repro.serve import VectorService
 
     asked = np.unique(qidx)
     with VectorService(batch_size=cfg["serving"]["batch_size"]) as svc:
-        svc.attach(COLLECTION, str(db),
-                   params=SearchParams(k=k, **cfg["search"]))
-        got = svc.search(COLLECTION, pool[asked], k=k)
+        kind.attach(svc, COLLECTION, str(db), cfg, k, None)
+        got = svc.search(COLLECTION, data.pool["queries"][asked], k=k)
     ids = np.stack([np.asarray(r.result.ids, np.int64).reshape(-1)
                     for r in got])
     return ids[np.searchsorted(asked, qidx)]

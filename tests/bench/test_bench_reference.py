@@ -1,24 +1,27 @@
 """bench/reference.py against a direct numpy sort, and the comparison of
-bench/check.py against its control and against planted faults."""
+bench/check.py, through the ``vectors_l2`` kind, against its control and
+against planted faults."""
 import sys
 from pathlib import Path
 
 import numpy as np
 import pytest
 
-sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
+ROOT = Path(__file__).resolve().parents[2]
+sys.path.insert(0, str(ROOT))
 
-from bench import check, corpus, reference  # noqa: E402
+from bench import check, corpus, reference, spec  # noqa: E402
 
 K = 10
 CFG = {"num_vectors": 1500, "dim": 128, "clusters": 16, "cluster_scale": 0.15}
 MIX = {"pool": 96, "query_noise": 0.1}
+KIND = spec.load_kind(ROOT / "bench" / "kinds" / "vectors_l2.py")
 
 
 @pytest.fixture(scope="module")
 def data():
-    x = corpus.make_corpus(CFG)
-    return x, corpus.make_pool(x, MIX)
+    d = KIND.data(CFG, MIX)
+    return d.corpus["vectors"], d.pool["queries"]
 
 
 def direct(x, q, k):
@@ -35,7 +38,8 @@ def test_exact_knn_is_a_direct_sort(data):
 
 
 def judge(x, q, ids, dists, unanswered=0):
-    return check.compare(x, q, np.arange(len(q)), ids, dists,
+    return check.compare(KIND, corpus.Data({"vectors": x}, {"queries": q}),
+                         np.arange(len(q)), ids, dists,
                          unanswered=unanswered, recall_floor=0.9)
 
 
@@ -87,7 +91,8 @@ def test_a_wrong_answer_fails(data, fault):
 def test_an_answer_that_never_came_fails(data):
     x, q = data
     ids, dists = reference.exact_knn(x, q, K)
-    checks = check.compare(x, q, np.arange(1, len(q)), ids[1:], dists[1:],
+    checks = check.compare(KIND, corpus.Data({"vectors": x}, {"queries": q}),
+                           np.arange(1, len(q)), ids[1:], dists[1:],
                            unanswered=1, recall_floor=0.9)
     assert checks["unanswered"]["value"] == 1
     assert not check.passed(checks)
@@ -96,8 +101,8 @@ def test_an_answer_that_never_came_fails(data):
 def test_every_seed_asks_the_same_data_in_another_order():
     """The data set is fixed; the seed draws only the order of the pool's
     queries, so every seed offers the same work."""
-    np.testing.assert_array_equal(corpus.make_corpus(CFG),
-                                  corpus.make_corpus(CFG))
+    np.testing.assert_array_equal(KIND.make_corpus(CFG),
+                                  KIND.make_corpus(CFG))
     a = corpus.query_order(2**31 + 5, 0, 96, 96 * 3)
     b = corpus.query_order(7, 0, 96, 96 * 3)
     for order in (a, b):

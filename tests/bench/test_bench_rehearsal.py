@@ -81,6 +81,33 @@ def test_an_open_loop_rehearsal_is_correct(db_cache):
     assert 0 < m["latency_p50_ms"] <= m["latency_p95_ms"]
 
 
+def test_the_counter_window_ends_with_the_window_while_tracing(
+        db_cache, monkeypatch):
+    """Stopping the profiler takes many seconds, past the window's end:
+    the engine's counters are read at the window's end all the same."""
+    orig = run.drive
+
+    def slow_trace(svc, at):
+        run.sleep_until(at)
+        time.sleep(3.0)
+        return 0.5, {"start": run.snapshot(svc), "end": run.snapshot(svc)}
+
+    def traced_drive(*args):
+        return orig(*args[:8], True, *args[9:])
+
+    monkeypatch.setattr(run, "_trace", slow_trace)
+    monkeypatch.setattr(run.trace_reduce, "read", lambda *a: None)
+    monkeypatch.setattr(run, "drive", traced_drive)
+    lines = []
+    r = run.run_cell(spec.load_cell("bigann128-budget25.bulk64"), SEED, 1.0,
+                     False, rehearsal=True, db_cache=db_cache,
+                     t_process=time.monotonic(), log=lines.append)
+    assert r["correct"], r["checks"]
+    window = next(ln for ln in lines if ln.startswith("window"))
+    counter_s = float(window.split("counters over ")[1].split("s)")[0])
+    assert abs(counter_s - 1.0) < 0.5, window
+
+
 def _broken(fn):
     """Wrap ``PageANNIndex.search`` so ``fn`` alters what it produces."""
     orig = index_mod.PageANNIndex.search
