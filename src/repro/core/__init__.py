@@ -9,13 +9,14 @@ from repro.core.config import (
     SearchParams,
 )
 from repro.core.delta import DeltaTier, MutableIndex
-from repro.core.filter import FilterExpr, MetadataSchema, Num, Tag
+from repro.core.filter import Bag, FilterExpr, MetadataSchema, Num, Tag
 from repro.core.index import PageANNIndex, recall_at_k
 from repro.core.persist import IndexFormatError, load_index
 from repro.core.protocol import MutableVectorIndex, VectorIndex
 
 __all__ = [
     "AdaptiveParams",
+    "Bag",
     "DeltaParams",
     "DeltaTier",
     "FilterExpr",

@@ -353,7 +353,7 @@ class MutableIndex:
         return DeltaTier(
             base.dim,
             self.delta_params.min_capacity,
-            n_tags=len(schema.tags) if schema is not None else 0,
+            n_tags=schema.tag_width if schema is not None else 0,
             n_nums=len(schema.numerics) if schema is not None else 0,
         )
 
@@ -575,11 +575,13 @@ class MutableIndex:
         """Extend the unified vocabulary with unseen tag values (appended,
         never reordered — base codes stay stable) and encode. Caller holds
         the index lock."""
-        for f in schema.tags:
+        bags = set(schema.bag_names)
+        for f in schema.tags + schema.bag_names:
+            vals = columns.get(f, ())
+            if f in bags:
+                vals = [v for bag in vals if bag for v in bag]
             have = set(self._vocab.get(f, ()))
-            new = sorted(
-                {str(v) for v in columns.get(f, ()) if v is not None} - have
-            )
+            new = sorted({str(v) for v in vals if v is not None} - have)
             if new:
                 self._vocab[f] = self._vocab.get(f, ()) + tuple(new)
         return filter_mod.encode_metadata(schema, self._vocab, columns, n)

@@ -100,6 +100,12 @@ def stack_shards(idxs, parts) -> ShardedIndex:
         )
 
     datas = [pad_mem(d) for d in datas]
+    # one LSH centre per shard, or none: a shard whose hyperplanes pass
+    # through the origin takes a zero centre when another shard has one
+    if any(d.lsh_center is not None for d in datas):
+        zero = jnp.zeros((datas[0].lsh_planes.shape[0],), jnp.float32)
+        datas = [d if d.lsh_center is not None
+                 else d._replace(lsh_center=zero) for d in datas]
     # cached_pages may differ in length; pad with a sentinel beyond range
     cmax = max(d.cached_pages.shape[0] for d in datas)
     datas = [
@@ -133,12 +139,14 @@ def make_sharded_search(
     params: SearchParams | None = None,
     shard_axis: str = "data",
     query_axis: str = "model",
+    center: bool = False,
 ):
     """Returns (jitted_fn, in_shardings) executing the sharded search.
 
     stacked SearchData leaves are sharded P(shard_axis); queries (Q, d) are
     sharded P(query_axis); outputs (Q, k) are sharded P(query_axis).
-    ``params`` defaults to the config's search knobs.
+    ``params`` defaults to the config's search knobs. ``center`` says
+    whether the stacked shards carry LSH centres (``lsh_center``).
     """
     p = (params or SearchParams.from_config(cfg)).replace(k=k)
     mode = cfg.memory_mode.value
@@ -179,9 +187,11 @@ def make_sharded_search(
         top_d = jnp.take_along_axis(flat_d, order, axis=1)
         return top_ids, top_tag, top_d, all_io.sum(0)
 
-    data_spec = jax.tree.map(lambda _: P(shard_axis), search_mod.SearchData(
-        *[0] * len(search_mod.SearchData._fields)
-    ))
+    fields = [0] * len(search_mod.SearchData._fields)
+    if not center:
+        fields[search_mod.SearchData._fields.index("lsh_center")] = None
+    data_spec = jax.tree.map(lambda _: P(shard_axis),
+                             search_mod.SearchData(*fields))
     in_specs = (data_spec, P(query_axis))
     out_specs = (P(query_axis), P(query_axis), P(query_axis), P(query_axis))
 

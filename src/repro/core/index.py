@@ -19,6 +19,7 @@ from __future__ import annotations
 import dataclasses
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -26,6 +27,7 @@ from repro.core import filter as filter_mod
 from repro.core import layout as layout_mod
 from repro.core import lsh as lsh_mod
 from repro.core import page_graph as pg_mod
+from repro.core import plan as plan_mod
 from repro.core import pq as pq_mod
 from repro.core import search as search_mod
 from repro.core import vamana as vamana_mod
@@ -38,6 +40,8 @@ from repro.core.config import (
     resolve_search_params,
 )
 from repro.core.filter import FilterExpr, MetaArrays, MetadataSchema
+from repro.core.plan import EXACT, QueryPlan
+from repro.obs.trace import phase
 
 PAD = -1
 
@@ -95,16 +99,33 @@ class PageANNIndex:
     # filtered search (``core.filter``): the declared metadata schema, the
     # tag vocabularies (field -> tuple of values; codes are positions),
     # slot-aligned device columns the page scan gathers masks from, and
-    # the original-order host copy (selectivity probe / brute-force
-    # oracle / compaction source). All None/empty without a schema.
+    # the original-order host copy (match counting / brute-force oracle /
+    # compaction source). All None/empty without a schema.
     schema: MetadataSchema | None = None
     vocab: dict = dataclasses.field(default_factory=dict)
     meta: MetaArrays | None = None
     meta_host: MetaArrays | None = None
-    # per-FilterExpr compiled form + measured selectivity (host cache —
-    # compiling and probing once per distinct predicate, like the jit
-    # executable the static arg keys)
-    _filter_cache: dict = dataclasses.field(default_factory=dict, repr=False)
+    # per tag value the ids carrying it (built from meta_host at build and
+    # load), and what the per-query path choice depends on (core.plan)
+    postings: filter_mod.Postings | None = dataclasses.field(
+        default=None, repr=False)
+    planner: plan_mod.Planner | None = dataclasses.field(
+        default=None, repr=False)
+
+    def __post_init__(self):
+        if self.schema is not None and self.postings is None:
+            self.postings = filter_mod.build_postings(
+                self.schema, self.vocab, self.meta_host.tags)
+        # value -> code per field, built once for every predicate's compile
+        self._codes = filter_mod.code_maps(self.vocab)
+        if self.planner is None:
+            self.planner = plan_mod.Planner(
+                live=self.store.num_vectors,
+                capacity=self.store.capacity,
+                rows_per_page=(self.store.padded_tile_bytes()
+                               // (self.store.dim * 4)),
+                device_bytes=_device_bytes(),
+            )
 
     # ------------------------------------------------------------------ build
     @staticmethod
@@ -269,7 +290,7 @@ class PageANNIndex:
     # ----------------------------------------------------------------- search
     def _raw_search(
         self, q: jnp.ndarray, params: SearchParams, mesh=None,
-        meta=None, cfilter=None,
+        meta=None, fshape=None, fvals=None,
     ) -> search_mod.SearchResult:
         if mesh is not None:
             if self.fetcher is not None:
@@ -283,7 +304,7 @@ class PageANNIndex:
                 mesh=mesh,
                 capacity=self.store.capacity,
                 mode=self.cfg.memory_mode.value,
-                meta=meta, cfilter=cfilter,
+                meta=meta, fshape=fshape, fvals=fvals,
             )
         if self.fetcher is not None:
             return search_mod.stream_search(
@@ -291,47 +312,177 @@ class PageANNIndex:
                 capacity=self.store.capacity,
                 mode=self.cfg.memory_mode.value,
                 fetcher=self.fetcher,
-                meta=meta, cfilter=cfilter,
+                meta=meta, fshape=fshape, fvals=fvals,
             )
         return search_mod.batch_search(
             q, self.data, params,
             capacity=self.store.capacity,
             mode=self.cfg.memory_mode.value,
-            meta=meta, cfilter=cfilter,
+            meta=meta, fshape=fshape, fvals=fvals,
         )
 
     # ----------------------------------------------------------------- filter
+    def _matches(self, expr: FilterExpr):
+        """(CompiledFilter, ascending original ids passing ``expr``)."""
+        cf = filter_mod.compile_filter(expr, self.schema, self._codes)
+        return cf, filter_mod.match_ids(
+            cf, self.postings, self.meta_host.tags, self.meta_host.nums)
+
     def compiled_filter(self, expr: FilterExpr):
         """Resolve a ``FilterExpr`` against this index's schema/vocab and
-        measure its selectivity (fraction of live vectors passing) over
-        the host metadata columns. Cached per expression — the compiled
-        form keys one jit executable, the selectivity drives the beam
-        oversampling. Returns (CompiledFilter, selectivity)."""
-        cached = self._filter_cache.get(expr)
-        if cached is not None:
-            return cached
-        cf = filter_mod.compile_filter(expr, self.schema, self.vocab)
-        mask = filter_mod.filter_mask_np(
-            cf, self.meta_host.tags, self.meta_host.nums
-        )
-        sel = float(mask.mean()) if mask.size else 0.0
-        self._filter_cache[expr] = (cf, sel)
-        return cf, sel
+        count its matches from the posting lists. Returns
+        (CompiledFilter, selectivity: the fraction of vectors passing)."""
+        cf, ids = self._matches(expr)
+        return cf, len(ids) / max(1, self.planner.live)
 
-    @staticmethod
-    def _filter_oversample(selectivity: float, cap: int) -> int:
-        """Pow2 beam-widening factor for a predicate's selectivity: a
-        filter passing 1/s of the corpus needs ~s× the frontier to
-        surface as many passing candidates as the unfiltered search —
-        bucketed to powers of two (bounded compiled shapes, like the
-        tombstone oversampling) and clamped to ``cap``."""
-        if selectivity <= 0.0:
-            return cap
-        need = 1.0 / selectivity
-        b = 1
-        while b < need and b < cap:
-            b *= 2
-        return min(b, cap)
+    def plan(
+        self,
+        expr: FilterExpr,
+        k: int | None = None,
+        params: SearchParams | None = None,
+        filter_params: FilterParams | None = None,
+    ) -> QueryPlan:
+        """Choose the path of one query filtered by ``expr`` from its match
+        count (``core.plan``): the exact scan of its matches, or the graph
+        with the beam oversampled for its selectivity. Queries whose
+        plans share a ``group`` share one compiled program and dispatch."""
+        return self.plan_many([expr], k, params, filter_params)[0]
+
+    def plan_many(
+        self,
+        exprs,
+        k: int | None = None,
+        params: SearchParams | None = None,
+        filter_params: FilterParams | None = None,
+    ) -> list:
+        """:meth:`plan` of each of ``exprs`` (a None stays None), with the
+        params, the threshold and each distinct predicate resolved once."""
+        p = self.resolve_params(k, params)
+        fp = filter_params if filter_params is not None else FilterParams()
+        cap = fp.max_filter_oversample
+        threshold = self.planner.threshold(p, cap)
+        done: dict = {}
+        for expr in exprs:
+            if expr is not None and expr not in done:
+                cf, ids = self._matches(expr)
+                done[expr] = self.planner.plan(
+                    cf, ids, self.store.old_to_new, p, cap, threshold)
+        return [None if e is None else done[e] for e in exprs]
+
+    def _plans(self, filter, n: int, p: SearchParams,
+               fp: FilterParams | None) -> list:
+        """One plan per query: ``filter`` is one ``FilterExpr`` for every
+        query, or a sequence of ``n`` ``FilterExpr``/``QueryPlan``s, or
+        None for a row whose answer is not wanted (a batch's padding)."""
+        if isinstance(filter, FilterExpr):
+            return self.plan_many([filter], params=p, filter_params=fp) * n
+        filter = list(filter)
+        if len(filter) != n:
+            raise ValueError(f"{len(filter)} filters for {n} queries")
+        for f in filter:
+            if not (f is None or isinstance(f, (QueryPlan, FilterExpr))):
+                raise TypeError(f"a per-query filter is a FilterExpr, a "
+                                f"QueryPlan or None, not "
+                                f"{type(f).__name__}")
+        exprs = [f if isinstance(f, FilterExpr) else None for f in filter]
+        if not any(e is not None for e in exprs):
+            return filter
+        planned = self.plan_many(exprs, params=p, filter_params=fp)
+        return [f if e is None else pl
+                for f, e, pl in zip(filter, exprs, planned)]
+
+    def _exact_scan(self, q: np.ndarray, cand: np.ndarray,
+                    row_query: np.ndarray, first_row: np.ndarray,
+                    n_rows: np.ndarray, k: int, slots: int):
+        """``search.posting_scan`` of packed candidate rows: their vectors
+        gathered on the device from the resident page records, or, for a
+        streamed index, from the host copy of the members."""
+        if self.fetcher is None:
+            return search_mod.posting_scan(
+                q, cand, row_query, first_row, n_rows, self.data, k=k,
+                slots=slots, capacity=self.store.capacity)
+        flat = self.store.vecs.reshape(-1, self.store.dim)
+        return search_mod.posting_scan(
+            q, cand, row_query, first_row, n_rows,
+            vecs=flat[np.maximum(cand, 0)], k=k, slots=slots)
+
+    def _exact_search(self, q: np.ndarray, plans: list, k: int,
+                      slots: int):
+        """The exact path of one group, one program whatever its queries'
+        match counts: each query's matches (a None plan has none) fill
+        whole rows of ``row_width(k)`` candidates, the queries' rows lie
+        back to back, padded to ``packed_rows`` of their count
+        (``core.plan``), and ``posting_scan`` scores them. Returns its
+        (ids, dists), not yet fetched from the device."""
+        width = plan_mod.row_width(k)
+        n_rows = np.array([0 if pl is None else pl.bucket for pl in plans],
+                          np.int32)
+        if n_rows.max(initial=0) > slots:
+            raise ValueError(f"an exact-path plan of {n_rows.max()} rows; "
+                             f"the exact program takes at most {slots}")
+        first = (np.cumsum(n_rows) - n_rows).astype(np.int32)
+        total = int(n_rows.sum())
+        rows = plan_mod.packed_rows(total)
+        cand = np.full(rows * width, PAD, np.int32)
+        for at, pl in zip(first * width, plans):
+            if pl is not None:
+                cand[at:at + pl.count] = pl.matches
+        row_query = np.zeros(rows, np.int32)
+        row_query[:total] = np.repeat(np.arange(len(plans), dtype=np.int32),
+                                      n_rows)
+        return self._exact_scan(q, cand.reshape(rows, width), row_query,
+                                first, n_rows, k, slots)
+
+    def _filtered_search(self, q, p: SearchParams, filter, fp, mesh):
+        """Plan each query of ``q`` ((Q, d) numpy), then run each group
+        (``core.plan``): an exact group as one program over the batch's
+        lanes, a graph group in chunks of ``GRAPH_LANES`` padded with
+        copies of its first row. Every program is dispatched before any
+        result is fetched, so the device runs them back to back. Rows
+        planned None get PAD answers."""
+        n = q.shape[0]
+        fp = fp if fp is not None else FilterParams()
+        with phase("filter.plan", cat="search", track="search", n=n):
+            plans = self._plans(filter, n, p, fp)
+            groups: dict = {}
+            for i, pl in enumerate(plans):
+                if pl is not None:
+                    groups.setdefault(pl.group, []).append(i)
+        out = dict(ids=np.full((n, p.k), PAD, np.int32),
+                   dists=np.full((n, p.k), np.inf, np.float32),
+                   **{f: np.zeros(n, np.int32) for f in (
+                       "ios", "hops", "cache_hits", "shared_reads")})
+        running = []
+        for group, rows in groups.items():
+            if group[1] == EXACT:
+                mine = set(rows)
+                top, dists = self._exact_search(
+                    q, [pl if i in mine else None
+                        for i, pl in enumerate(plans)],
+                    p.k, self.planner.slots(p, fp.max_filter_oversample))
+                running.append((rows, np.asarray(rows),
+                                {"ids": top, "dists": dists}))
+                continue
+            shape, _, bucket = group
+            lanes = plan_mod.GRAPH_LANES
+            for lo in range(0, len(rows), lanes):
+                part = rows[lo:lo + lanes]
+                take = part + part[:1] * (lanes - len(part))
+                sub = [plans[i] for i in take]
+                vals = search_mod.FilterValues(
+                    codes=np.stack([s.filter.codes for s in sub]),
+                    bounds=np.stack([s.filter.bounds for s in sub]))
+                res = self._raw_search(
+                    jnp.asarray(q[take]),
+                    p.replace(beam_width=p.beam_width * bucket),
+                    mesh=mesh, meta=self.meta, fshape=shape, fvals=vals)
+                running.append((part, slice(len(part)), {
+                    f: getattr(res, f) for f in out
+                    if getattr(res, f) is not None}))
+        for part, at, got in running:
+            for f, v in got.items():
+                out[f][part] = np.asarray(v)[at]
+        return search_mod.SearchResult(**out)
 
     def metadata_by_original_id(self) -> dict[str, list] | None:
         """Decoded metadata columns in ORIGINAL id order (missing ->
@@ -387,27 +538,25 @@ class PageANNIndex:
         mesh routes through ``shard_search`` (query batch split across it).
 
         ``filter`` restricts results to vectors whose metadata satisfies
-        the predicate (see ``core.filter``): the compiled filter masks
-        non-passing members to ``+inf`` inside the page scan, and the
-        beam is widened by a pow2 factor of the predicate's measured
-        selectivity (bounded by
-        ``filter_params.max_filter_oversample``) so recall matches a
-        post-filter brute force. ``filter=None`` compiles and runs the
-        exact pre-filter program.
+        the predicate (see ``core.filter``): one ``FilterExpr`` for every
+        query, or a sequence of one per query (``FilterExpr``, a
+        ``QueryPlan`` from :meth:`plan`, or None for a padding row whose
+        answer is not wanted: it gets PAD ids). Each query takes its own path
+        (``core.plan``): a selective predicate's matches are scanned
+        exactly, a broad one's run through the graph with the predicate
+        masking members to ``+inf`` inside the page scan and the beam
+        widened by a pow2 factor of its selectivity (bounded by
+        ``filter_params.max_filter_oversample``). Predicates of one shape
+        share one compiled program per path and bucket. ``filter=None``
+        compiles and runs the exact pre-filter program.
         """
         p = self.resolve_params(k, params)
-        meta = cfilter = None
         if filter is not None:
-            fp = filter_params if filter_params is not None else FilterParams()
-            cfilter, sel = self.compiled_filter(filter)
-            factor = self._filter_oversample(sel, fp.max_filter_oversample)
-            if factor > 1:
-                p = p.replace(beam_width=p.beam_width * factor)
-            meta = self.meta
-        res = self._raw_search(
-            jnp.asarray(queries, jnp.float32), p, mesh=mesh,
-            meta=meta, cfilter=cfilter,
-        )
+            res = self._filtered_search(np.asarray(queries, np.float32), p,
+                                        filter, filter_params, mesh)
+        else:
+            res = self._raw_search(jnp.asarray(queries, jnp.float32), p,
+                                   mesh=mesh)
         return search_mod.SearchResult(
             ids=self.translate_ids(res.ids),
             dists=np.asarray(res.dists),
@@ -416,6 +565,41 @@ class PageANNIndex:
             cache_hits=np.asarray(res.cache_hits),
             shared_reads=np.asarray(res.shared_reads),
         )
+
+    def precompile_filtered(
+        self,
+        example: FilterExpr,
+        *,
+        batch_size: int,
+        k: int | None = None,
+        params: SearchParams | None = None,
+        filter_params: FilterParams | None = None,
+    ) -> int:
+        """Compile, for batches of ``batch_size``, every program a
+        predicate of ``example``'s shape can be planned to
+        (``core.plan.Planner.buckets``): each packed size of the exact
+        path and each beam factor of the graph's; returns how many. A
+        server that calls this before taking traffic compiles nothing when
+        a new predicate arrives."""
+        p = self.resolve_params(k, params)
+        fp = filter_params if filter_params is not None else FilterParams()
+        cap = fp.max_filter_oversample
+        cf = filter_mod.compile_filter(example, self.schema, self.vocab)
+        q = np.zeros((batch_size, self.dim), np.float32)
+        groups = self.planner.buckets(p, cap, batch_size)
+        slots = self.planner.slots(p, cap)
+        none = np.zeros(batch_size, np.int32)
+        for path, size in groups:
+            if path == EXACT:
+                jax.block_until_ready(self._exact_scan(
+                    q, np.full((size, plan_mod.row_width(p.k)), PAD,
+                               np.int32),
+                    np.zeros(size, np.int32), none, none, p.k, slots))
+            else:
+                self.search(q, params=p, filter=[QueryPlan(
+                    cf, path, size, self.planner.live)] * batch_size,
+                    filter_params=fp)
+        return len(groups)
 
     def profile(
         self,
@@ -448,19 +632,30 @@ class PageANNIndex:
                 "supported: reload without memory_budget to profile"
             )
         p = self.resolve_params(k, params)
-        meta = cfilter = None
+        meta = fshape = fvals = None
         if filter is not None:
+            # the hop loop's trail: every query on the graph path, the beam
+            # widened for the most selective of them
             fp = filter_params if filter_params is not None else FilterParams()
-            cfilter, sel = self.compiled_filter(filter)
-            factor = self._filter_oversample(sel, fp.max_filter_oversample)
-            if factor > 1:
-                p = p.replace(beam_width=p.beam_width * factor)
+            plans = self._plans(filter, len(queries), p, fp)
+            shapes = {pl.filter.shape for pl in plans}
+            if len(shapes) != 1:
+                raise ValueError("profile() takes filters of one shape")
+            fshape = shapes.pop()
+            factor = max(self.planner.factor(pl.count, p,
+                                             fp.max_filter_oversample)
+                         for pl in plans)
+            p = p.replace(beam_width=p.beam_width * factor)
             meta = self.meta
+            fvals = search_mod.FilterValues(
+                codes=jnp.asarray(np.stack([pl.filter.codes for pl in plans])),
+                bounds=jnp.asarray(np.stack([pl.filter.bounds
+                                             for pl in plans])))
         res, trail = search_mod.profile_search(
             jnp.asarray(queries, jnp.float32), self.data, p,
             capacity=self.store.capacity,
             mode=self.cfg.memory_mode.value,
-            meta=meta, cfilter=cfilter,
+            meta=meta, fshape=fshape, fvals=fvals,
         )
         res = search_mod.SearchResult(
             ids=self.translate_ids(np.asarray(res.ids)),
@@ -697,3 +892,14 @@ def recall_at_k(found_ids: np.ndarray, truth_ids: np.ndarray) -> float:
     dup = ((truth[:, :, None] == truth[:, None, :])
            & (j[None, None, :] < j[None, :, None])).any(-1)        # (Q, k)
     return float((present & ~dup).sum() / (q * k))
+
+
+def _device_bytes() -> int:
+    """Memory of the default device as its backend reports it, else a TPU
+    v5e's 16 GiB (the CPU backend reports none)."""
+    try:
+        stats = jax.devices()[0].memory_stats() or {}
+    except Exception:       # a backend without memory statistics
+        stats = {}
+    return int(stats.get("bytes_limit", plan_mod.DEFAULT_DEVICE_BYTES))
+

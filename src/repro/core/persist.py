@@ -192,7 +192,7 @@ def _load_meta(directory: str, doc: dict, store):
         raise IndexFormatError(
             f"{directory}: garbled manifest schema section: {e}"
         )
-    unknown = sorted(set(vocab) - set(schema.tags))
+    unknown = sorted(set(vocab) - set(schema.tags) - set(schema.bag_names))
     if unknown:
         raise IndexFormatError(
             f"{directory}: manifest vocab names fields not in the "
@@ -207,7 +207,7 @@ def _load_meta(directory: str, doc: dict, store):
         slot_tags = np.asarray(z["tags"], np.int32)
         slot_nums = np.asarray(z["nums"], np.float32)
     rows = int(np.asarray(store.new_to_old).shape[0])  # pages * capacity
-    want_tags = (rows, len(schema.tags))
+    want_tags = (rows, schema.tag_width)
     want_nums = (rows, len(schema.numerics))
     if slot_tags.shape != want_tags or slot_nums.shape != want_nums:
         raise IndexFormatError(
@@ -215,6 +215,16 @@ def _load_meta(directory: str, doc: dict, store):
             f"disagree with the manifest schema — expected "
             f"{want_tags}/{want_nums}"
         )
+    # every stored code must name a vocabulary value: the posting lists
+    # built from these columns index by code
+    spans = [(i, 1, f) for i, f in enumerate(schema.tags)] + [
+        (c0, w, f) for f, (c0, w) in schema.bag_columns().items()]
+    for c0, w, f in spans:
+        if slot_tags[:, c0:c0 + w].max(initial=-1) >= len(vocab.get(f, ())):
+            raise IndexFormatError(
+                f"{path}: field {f!r} holds codes outside its vocabulary "
+                f"of {len(vocab.get(f, ()))} values"
+            )
     host_tags, host_nums = layout_mod.unreassign_metadata(
         slot_tags, slot_nums, store
     )
@@ -277,6 +287,10 @@ def save_pageann(index, directory: str) -> None:
         lsh_sample_ids=np.asarray(lsh.sample_ids),
         lsh_sample_codes=np.asarray(lsh.sample_codes),
         lsh_sample_pq=np.asarray(lsh.sample_pq),
+        # the hyperplanes' centre, where the build moved it off the origin
+        # (core.lsh); an artifact without it hashes about the origin
+        **({} if lsh.center is None
+           else {"lsh_center": np.asarray(lsh.center, np.float32)}),
     )
     if getattr(index, "schema", None) is not None:
         # page-slot-aligned metadata columns ride their own sidecar: the
@@ -471,6 +485,8 @@ def load_pageann(directory: str, *, memory_budget=None):
         sample_ids=jnp.asarray(arrays["lsh_sample_ids"]),
         sample_codes=jnp.asarray(arrays["lsh_sample_codes"]),
         sample_pq=jnp.asarray(arrays["lsh_sample_pq"]),
+        center=(jnp.asarray(arrays["lsh_center"]) if "lsh_center" in arrays
+                else None),
     )
     # stats.disk_bytes reports the persisted artifact as it sits on disk,
     # not a recomputation from device arrays (see BuildStats docstring)

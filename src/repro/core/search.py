@@ -46,11 +46,20 @@ a device op's scope in a profiler trace names its stage: ``hop_select``,
 ``hop_scan`` (the page-scan kernels and their member masking),
 ``hop_fetch`` (the streamed path's host callback alone),
 ``hop_cache_probe``, ``hop_nbr_adc``, ``hop_cand_probe``, ``hop_dedupe``
-and ``hop_merge``. ``batch_search`` also keeps each lane's scheduled
+and ``hop_merge``; a filtered search evaluates its predicate over a
+hop's pages in ``hop_filter``. ``batch_search`` also keeps each lane's scheduled
 pages per hop and reports, per query, the reads of a page that a
 lower-numbered lane scanned at the same hop (``SearchResult.shared_reads``):
 what a hop kernel that reads each shared page once would save. That
 bookkeeping runs under a scope of its own, ``shared_reads``.
+
+Filtered search carries its predicate as data: the static
+:class:`repro.core.filter.FilterShape` keys the program, and each query's
+codes and numeric bounds (:class:`FilterValues`) are vmapped beside the
+queries, so one executable serves every predicate of a shape. The exact
+path of a selective predicate (``core.plan``) bypasses the graph:
+``gather_members`` and ``posting_scan`` score the vectors the posting
+lists name, in scope ``posting_scan``.
 """
 from __future__ import annotations
 
@@ -64,10 +73,11 @@ from jax.sharding import PartitionSpec as P
 from repro.core import compat
 from repro.core import pq as pq_mod
 from repro.core.config import MemoryMode, SearchParams
-from repro.core.filter import CompiledFilter, MetaArrays, filter_mask
+from repro.core.filter import FilterShape, MetaArrays, filter_mask
 from repro.core.layout import MemoryTier, PageStore
 from repro.core.lsh import LSHIndex, hash_codes
 from repro.kernels import ops
+from repro.kernels.record_layout import rows_per_vector, vectors_per_row
 
 PAD = -1
 INF = jnp.inf
@@ -98,6 +108,18 @@ class SearchData(NamedTuple):
     lsh_ids: jnp.ndarray
     lsh_codes: jnp.ndarray
     lsh_pq: jnp.ndarray        # (S, M_disk)
+    # (d,) point the LSH hyperplanes pass through; None where they pass
+    # through the origin (see core.lsh.build_lsh)
+    lsh_center: jnp.ndarray | None = None
+
+
+class FilterValues(NamedTuple):
+    """Per-query values of a compiled filter of one ``FilterShape``:
+    ``codes`` (Q, n_codes) int32 and ``bounds`` (Q, n_num, 2) float32
+    (``CompiledFilter.codes`` / ``.bounds`` stacked)."""
+
+    codes: jnp.ndarray
+    bounds: jnp.ndarray
 
 
 def make_search_data(store: PageStore, tier: MemoryTier, lsh: LSHIndex) -> SearchData:
@@ -120,6 +142,7 @@ def make_search_data(store: PageStore, tier: MemoryTier, lsh: LSHIndex) -> Searc
         lsh_ids=lsh.sample_ids,
         lsh_codes=lsh.sample_codes,
         lsh_pq=lsh.sample_pq,
+        lsh_center=lsh.center,
     )
 
 
@@ -239,7 +262,8 @@ def init_state(
     are masked to PAD/INF in place, never compacted.
     """
     num_pages = data.resident_map.shape[0]
-    qcode = hash_codes(q[None], data.lsh_planes)[0]
+    qh = q if data.lsh_center is None else q - data.lsh_center
+    qcode = hash_codes(qh[None], data.lsh_planes)[0]
     ham = ops.hamming(data.lsh_codes, qcode)
     ham_top, top = _top_k_merge(ham.astype(jnp.float32), entries)
     entry_ids = data.lsh_ids[top].astype(jnp.int32)
@@ -343,24 +367,27 @@ def in_beam(nids: jnp.ndarray, cand_ids: jnp.ndarray) -> jnp.ndarray:
     return (nids[:, None] == cand_ids[None, :]).any(-1)
 
 
+@jax.named_scope("hop_filter")
 def page_member_mask(
-    meta: MetaArrays, cfilter: CompiledFilter, batch: jnp.ndarray,
-    *, capacity: int,
+    meta: MetaArrays, fshape: FilterShape, fvals: FilterValues,
+    batch: jnp.ndarray, *, capacity: int,
 ) -> jnp.ndarray:
-    """Evaluate a compiled filter over one hop's page batch.
+    """Evaluate one query's filter over one hop's page batch.
 
     ``meta`` holds page-slot-aligned metadata columns ((P*cap, T) tags /
     (P*cap, N) numerics — the same ``new_to_old`` layout the page records
     use), so a page's rows are one contiguous slice: gather the (b,)
-    batch and evaluate the predicate to a (b, cap) f32 mask (1 = passes).
-    Pad slots carry the missing sentinels (-1 / NaN) and never pass.
+    batch and evaluate the predicate (``fshape`` static, ``fvals`` this
+    query's codes and bounds) to a (b, cap) f32 mask (1 = passes). Pad
+    slots carry the missing sentinels (-1 / NaN) and never pass.
     """
     # explicit page count: a zero-width column block (schema with no tag
     # or no numeric fields) cannot infer it from a -1 reshape
     pages = meta.tags.shape[0] // capacity
     tags = meta.tags.reshape(pages, capacity, meta.tags.shape[-1])[batch]
     nums = meta.nums.reshape(pages, capacity, meta.nums.shape[-1])[batch]
-    return filter_mask(cfilter, tags, nums).astype(jnp.float32)
+    return filter_mask(fshape, fvals.codes, fvals.bounds, tags,
+                       nums).astype(jnp.float32)
 
 
 def score_page_batch(
@@ -375,7 +402,8 @@ def score_page_batch(
     mode: str,
     fetch=None,
     meta: MetaArrays | None = None,
-    cfilter: CompiledFilter | None = None,
+    fshape: FilterShape | None = None,
+    fvals: FilterValues | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Batched page-record read (Fig. 6 steps 2-4, THE I/O) -> both score
     sets from one DMA per page.
@@ -400,7 +428,8 @@ def score_page_batch(
     ``fetch=None`` (fully resident) keeps the one-array fused scan
     untouched.
 
-    With a filter bound (``meta`` + ``cfilter``), the predicate is
+    With a filter bound (``meta``, ``fshape`` and this query's
+    ``fvals``), the predicate is
     evaluated over the batch's page-slot-aligned metadata and pushed into
     the scan as a member mask: filtered-out members score ``+inf`` INSIDE
     the kernel, so the running result top-k only ever holds passing
@@ -424,8 +453,8 @@ def score_page_batch(
         # the mask is a function of the page id alone, so the SAME (b, cap)
         # mask applies to the resident and staged lanes of the hop
         scan["member_mask"] = (
-            page_member_mask(meta, cfilter, safe, capacity=cap)
-            if meta is not None and cfilter is not None
+            page_member_mask(meta, fshape, fvals, safe, capacity=cap)
+            if fshape is not None
             else None
         )
         if fetch is None:
@@ -582,7 +611,8 @@ def _search_one(
     entry_slack: int | None = None,
     min_entries: int = 1,
     meta: MetaArrays | None = None,
-    cfilter: CompiledFilter | None = None,
+    fshape: FilterShape | None = None,
+    fvals: FilterValues | None = None,
 ):
     disk_lut = pq_mod.pq_lut(q, data.disk_codebooks)  # (M_disk, ksub)
     # the finer in-memory LUT is dead weight in DISK_ONLY mode — skip it
@@ -622,7 +652,7 @@ def _search_one(
         mids, md, nids, nd, io_delta, hit_delta = score_page_batch(
             q, data, batch, state, disk_lut, mem_lut,
             capacity=capacity, mode=mode, fetch=fetch,
-            meta=meta, cfilter=cfilter,
+            meta=meta, fshape=fshape, fvals=fvals,
         )
         return merge(
             state, mids, md, nids, nd, io_delta, hit_delta,
@@ -652,7 +682,8 @@ def _batch_search_impl(
     entry_slack: int | None = None,
     min_entries: int = 1,
     meta: MetaArrays | None = None,
-    cfilter: CompiledFilter | None = None,
+    fshape: FilterShape | None = None,
+    fvals: FilterValues | None = None,
 ) -> SearchResult:
     fn = functools.partial(
         _search_one,
@@ -670,9 +701,15 @@ def _batch_search_impl(
         entry_slack=entry_slack,
         min_entries=min_entries,
         meta=meta,
-        cfilter=cfilter,
+        fshape=fshape,
     )
-    ids, dists, ios, hops, hits, trail = jax.vmap(fn)(queries, valid)
+    if fshape is None:
+        out = jax.vmap(fn)(queries, valid)
+    else:
+        # one query's codes and bounds ride beside it
+        out = jax.vmap(lambda q, v, fv: fn(q, v, fvals=fv))(
+            queries, valid, fvals)
+    ids, dists, ios, hops, hits, trail = out
     return SearchResult(ids=ids, dists=dists, ios=ios, hops=hops,
                         cache_hits=hits, shared_reads=_shared_reads(trail))
 
@@ -701,7 +738,7 @@ def _impl_kwargs(params: SearchParams, capacity: int, mode: str) -> dict:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("params", "capacity", "mode", "cfilter")
+    jax.jit, static_argnames=("params", "capacity", "mode", "fshape")
 )
 def batch_search(
     queries: jnp.ndarray,
@@ -711,7 +748,8 @@ def batch_search(
     capacity: int,
     mode: str,
     meta: MetaArrays | None = None,
-    cfilter: CompiledFilter | None = None,
+    fshape: FilterShape | None = None,
+    fvals: FilterValues | None = None,
 ) -> SearchResult:
     """Search a batch of queries. queries: (Q, d).
 
@@ -722,15 +760,17 @@ def batch_search(
     build-time properties of the index artifact.
 
     Filtered search binds ``meta`` (page-slot-aligned metadata columns, a
-    dynamic pytree) and ``cfilter`` (the compiled predicate — frozen
-    tuples, another static arg, so each distinct predicate keys its own
-    executable). Both default to ``None``, and because ``meta`` is an
-    argument rather than a ``SearchData`` field, the no-filter call keeps
-    the exact pre-filter jit signature and traces the identical program.
+    dynamic pytree), ``fshape`` (the predicates' static shape, one
+    executable per shape) and ``fvals`` (each query's codes and bounds,
+    (Q, ...) arrays vmapped beside the queries: any predicate of the
+    shape, no recompile). All default to ``None``, and because ``meta``
+    is an argument rather than a ``SearchData`` field, the no-filter call
+    keeps the exact pre-filter jit signature and traces the identical
+    program.
     """
     valid = jnp.ones((queries.shape[0],), bool)
     return _batch_search_impl(
-        queries, data, valid, meta=meta, cfilter=cfilter,
+        queries, data, valid, meta=meta, fshape=fshape, fvals=fvals,
         **_impl_kwargs(params, capacity, mode),
     )
 
@@ -742,18 +782,17 @@ def batch_search(
 @functools.lru_cache(maxsize=32)
 def _stream_search_fn(
     fetcher, params: SearchParams, capacity: int, mode: str,
-    cfilter: CompiledFilter | None = None,
+    fshape: FilterShape | None = None,
 ):
     """jitted streaming search bound to one host fetcher.
 
-    Cached per (fetcher, params, capacity, mode, cfilter): the fetcher is
+    Cached per (fetcher, params, capacity, mode, fshape): the fetcher is
     baked into the executable as the hop body's host callback, so two
     streamed indexes never share a compiled closure — mirrored in the
     serving layer's compile-cache key
     (``serve.compile_cache.geometry_of``). The fetcher participates in
     the lru key by identity, which is exactly the sharing rule we want;
-    the compiled filter (frozen tuples) participates by value, one
-    executable per distinct predicate.
+    the filter shape participates by value, one executable per shape.
     """
     kwargs = _impl_kwargs(params, capacity, mode)
     rows, lanes = fetcher.record_shape
@@ -769,10 +808,10 @@ def _stream_search_fn(
         )
 
     @jax.jit
-    def fn(queries, data, valid, meta=None):
+    def fn(queries, data, valid, meta=None, fvals=None):
         return _batch_search_impl(
-            queries, data, valid, fetch=fetch, meta=meta, cfilter=cfilter,
-            **kwargs,
+            queries, data, valid, fetch=fetch, meta=meta, fshape=fshape,
+            fvals=fvals, **kwargs,
         )
 
     return fn
@@ -787,7 +826,8 @@ def stream_search(
     mode: str,
     fetcher,
     meta: MetaArrays | None = None,
-    cfilter: CompiledFilter | None = None,
+    fshape: FilterShape | None = None,
+    fvals: FilterValues | None = None,
 ) -> SearchResult:
     """``batch_search`` over a budgeted index: ``data.page_recs`` holds
     only the resident page subset, and each hop's misses are pulled from
@@ -804,9 +844,9 @@ def stream_search(
     queries in the body until the whole batch exits, and their discarded
     hops still fetch.)
     """
-    fn = _stream_search_fn(fetcher, params, capacity, mode, cfilter)
+    fn = _stream_search_fn(fetcher, params, capacity, mode, fshape)
     valid = jnp.ones((queries.shape[0],), bool)
-    return fn(queries, data, valid, meta)
+    return fn(queries, data, valid, meta, fvals)
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
@@ -876,7 +916,8 @@ def _profile_one(
     entry_slack: int | None = None,
     min_entries: int = 1,
     meta: MetaArrays | None = None,
-    cfilter: CompiledFilter | None = None,
+    fshape: FilterShape | None = None,
+    fvals: FilterValues | None = None,
 ):
     """``_search_one`` with the per-hop trail recorded.
 
@@ -918,7 +959,7 @@ def _profile_one(
         mids, md, nids, nd, io_delta, hit_delta = score_page_batch(
             q, data, batch, st, disk_lut, mem_lut,
             capacity=capacity, mode=mode, fetch=fetch,
-            meta=meta, cfilter=cfilter,
+            meta=meta, fshape=fshape, fvals=fvals,
         )
         st = merge(
             st, mids, md, nids, nd, io_delta, hit_delta,
@@ -947,7 +988,7 @@ def _profile_one(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("params", "capacity", "mode", "cfilter")
+    jax.jit, static_argnames=("params", "capacity", "mode", "fshape")
 )
 def profile_search(
     queries: jnp.ndarray,
@@ -957,7 +998,8 @@ def profile_search(
     capacity: int,
     mode: str,
     meta: MetaArrays | None = None,
-    cfilter: CompiledFilter | None = None,
+    fshape: FilterShape | None = None,
+    fvals: FilterValues | None = None,
 ) -> tuple[SearchResult, HopProfile]:
     """``batch_search`` plus the per-hop trail (opt-in debug mode).
 
@@ -970,10 +1012,14 @@ def profile_search(
     """
     valid = jnp.ones((queries.shape[0],), bool)
     fn = functools.partial(
-        _profile_one, data=data, meta=meta, cfilter=cfilter,
+        _profile_one, data=data, meta=meta, fshape=fshape,
         **_impl_kwargs(params, capacity, mode),
     )
-    res, trail = jax.vmap(lambda q, v: fn(q, v))(queries, valid)
+    if fshape is None:
+        res, trail = jax.vmap(lambda q, v: fn(q, v))(queries, valid)
+    else:
+        res, trail = jax.vmap(lambda q, v, fv: fn(q, v, fvals=fv))(
+            queries, valid, fvals)
     ids, dists, ios, hops, hits = res
     pages, hio, hhits, active, worst, stall = trail
     return (
@@ -991,31 +1037,35 @@ def profile_search(
 @functools.lru_cache(maxsize=32)
 def _shard_search_fn(
     mesh, params: SearchParams, capacity: int, mode: str,
-    cfilter: CompiledFilter | None = None, with_meta: bool = False,
+    fshape: FilterShape | None = None, center: bool = False,
 ):
     """jitted shard_map: queries split over every mesh axis, data replicated.
 
-    Cached per (mesh, params, capacity, mode, cfilter, with_meta) so
+    Cached per (mesh, params, capacity, mode, fshape, center) so
     repeated serving calls reuse the compiled executable. Filtered
-    dispatches replicate the metadata columns like the index arrays
-    (``with_meta``); the no-filter entry builds the exact pre-filter
-    shard_map signature.
+    dispatches replicate the metadata columns like the index arrays and
+    split the per-query filter values with the queries; the no-filter
+    entry builds the exact pre-filter shard_map signature. ``center``
+    says whether the index's LSH hyperplanes carry a centre.
     """
     axes = tuple(mesh.axis_names)
     local = functools.partial(
         _batch_search_impl, **_impl_kwargs(params, capacity, mode)
     )
-    data_spec = jax.tree.map(
-        lambda _: P(), SearchData(*[0] * len(SearchData._fields))
-    )
-    if with_meta:
-        def local_meta(queries, data, valid, meta):
-            return local(queries, data, valid, meta=meta, cfilter=cfilter)
+    fields = [0] * len(SearchData._fields)
+    if not center:
+        fields[SearchData._fields.index("lsh_center")] = None
+    data_spec = jax.tree.map(lambda _: P(), SearchData(*fields))
+    if fshape is not None:
+        def local_meta(queries, data, valid, meta, fvals):
+            return local(queries, data, valid, meta=meta, fshape=fshape,
+                         fvals=fvals)
 
         fn = compat.shard_map(
             local_meta,
             mesh=mesh,
-            in_specs=(P(axes), data_spec, P(axes), MetaArrays(P(), P())),
+            in_specs=(P(axes), data_spec, P(axes), MetaArrays(P(), P()),
+                      FilterValues(P(axes), P(axes))),
             out_specs=P(axes),
         )
     else:
@@ -1037,7 +1087,8 @@ def shard_search(
     capacity: int,
     mode: str,
     meta: MetaArrays | None = None,
-    cfilter: CompiledFilter | None = None,
+    fshape: FilterShape | None = None,
+    fvals: FilterValues | None = None,
 ) -> SearchResult:
     """``batch_search`` with the query batch sharded across a device mesh.
 
@@ -1057,7 +1108,7 @@ def shard_search(
 
         mesh = make_host_mesh()
     fn = _shard_search_fn(
-        mesh, params, capacity, mode, cfilter, meta is not None
+        mesh, params, capacity, mode, fshape, data.lsh_center is not None
     )
     num_dev = 1
     for n in mesh.shape.values():
@@ -1070,7 +1121,11 @@ def shard_search(
             [queries, jnp.zeros((pad, queries.shape[1]), queries.dtype)]
         )
         valid = jnp.concatenate([valid, jnp.zeros((pad,), bool)])
-    res = fn(queries, data, valid, meta) if meta is not None else fn(
+        if fvals is not None:
+            fvals = jax.tree.map(
+                lambda a: jnp.concatenate(
+                    [a, jnp.zeros((pad,) + a.shape[1:], a.dtype)]), fvals)
+    res = fn(queries, data, valid, meta, fvals) if fshape is not None else fn(
         queries, data, valid
     )
     if pad:
@@ -1078,3 +1133,106 @@ def shard_search(
     return res
 
 
+# --------------------------------------------------------------------------
+# exact path of a selective predicate: score the vectors its posting lists
+# name (``core.plan``), no graph
+# --------------------------------------------------------------------------
+
+def gather_members(
+    page_recs: jnp.ndarray,
+    resident_map: jnp.ndarray,
+    ids: jnp.ndarray,
+    *,
+    capacity: int,
+    dim: int,
+) -> jnp.ndarray:
+    """(Q, B, dim) f32 member vectors of the reassigned ``ids`` (Q, B),
+    read from the resident page records (every page resident): vector
+    ``v`` sits in the record of page ``v // capacity`` at the slot
+    ``v % capacity`` of the packing ``core.layout.pack_page_records``
+    lays out. Whole 128-lane record rows are gathered (a row holds
+    several vectors for dim <= 128, a vector spans several rows above),
+    and the vector's lanes taken from them: a per-vector dynamic slice
+    lowers on a TPU to a loop that held most of the chip's time. PAD ids
+    read slot 0 of page 0; their scores are masked."""
+    safe = jnp.maximum(ids, 0)
+    slot = safe % capacity
+    rows, lanes = page_recs.shape[1:]
+    first = resident_map[safe // capacity] * rows         # the record's row
+    flat = page_recs.reshape(-1, lanes)
+    if dim <= lanes:
+        vpr = vectors_per_row(dim)
+        got = jnp.take(flat, first + slot // vpr, axis=0, mode="clip")
+        got = got[..., :vpr * dim].reshape(*ids.shape, vpr, dim)
+        pick = (slot % vpr)[..., None, None]
+        return jnp.take_along_axis(got, pick, axis=-2)[..., 0, :]
+    rpv = rows_per_vector(dim)
+    row = (first + slot * rpv)[..., None] + jnp.arange(rpv)
+    got = jnp.take(flat, row, axis=0, mode="clip")        # (Q, B, rpv, lanes)
+    return got.reshape(*ids.shape, rpv * lanes)[..., :dim]
+
+
+def _exact_top_k(queries, vecs, cand, row_query, first_row, n_rows,
+                 k: int, slots: int):
+    """Score each row's candidates against its query and keep the row's
+    nearest k (ties to the lower position), then fold each query's rows'
+    picks, in row order, into an empty result top-k with the hop's own
+    ``merge``: the exact path is one hop whose members are the query's
+    matches."""
+    diff = vecs - queries[row_query][:, None, :]
+    d = jnp.where(cand >= 0, jnp.sum(diff * diff, axis=-1), INF)   # (R, W)
+    row_d, pos = _top_k_merge(d, k)                                # (R, k)
+    row_ids = jnp.take_along_axis(cand, pos, axis=-1)
+    j = jnp.arange(slots)
+    own = j < n_rows[:, None]                                      # (Q, slots)
+    at = jnp.where(own, first_row[:, None] + j, 0)
+    q = queries.shape[0]
+    mem_ids = jnp.where(own[..., None], row_ids[at], PAD).reshape(q, -1)
+    mem_d = jnp.where(own[..., None], row_d[at], INF).reshape(q, -1)
+
+    def fold(ids_q, d_q):
+        none = jnp.zeros((0,), jnp.int32)
+        empty = BeamState(
+            cand_ids=none, cand_d=none.astype(jnp.float32),
+            cand_vis=none.astype(bool), page_vis=none.astype(bool),
+            res_ids=jnp.full((k,), PAD, jnp.int32),
+            res_d=jnp.full((k,), INF, jnp.float32),
+            io=jnp.int32(0), cache_hits=jnp.int32(0), hops=jnp.int32(0))
+        out = merge(empty, ids_q, d_q, none, none.astype(jnp.float32),
+                    jnp.int32(0), jnp.int32(0))
+        return out.res_ids, out.res_d
+
+    return jax.vmap(fold)(mem_ids, mem_d)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "capacity", "slots"))
+def posting_scan(
+    queries: jnp.ndarray,
+    cand: jnp.ndarray,
+    row_query: jnp.ndarray,
+    first_row: jnp.ndarray,
+    n_rows: jnp.ndarray,
+    data: SearchData | None = None,
+    vecs: jnp.ndarray | None = None,
+    *,
+    k: int,
+    slots: int,
+    capacity: int = 0,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """Exact top-k of each query (Q, d) over its candidates, packed as
+    rows: ``cand`` (R, W) holds reassigned ids (PAD = -1 never scores),
+    each row one query's (``row_query`` (R,)), and query ``i``'s rows are
+    the ``n_rows[i]`` (at most ``slots``) from ``first_row[i]`` on, its
+    candidates in ascending original-id order. Their vectors are gathered
+    from ``data``'s resident page records (``gather_members``), or given
+    as ``vecs`` (R, W, d) where the pages are not all resident. Squared L2
+    as sums of squared differences in float32 (no matmul, so no matmul
+    precision to choose), ties to the lower candidate position
+    (``lax.top_k``, per row and in ``merge``). Returns (ids (Q, k) int32,
+    PAD where fewer than k candidates, dists (Q, k), INF there)."""
+    with jax.named_scope("posting_scan"):
+        if vecs is None:
+            vecs = gather_members(data.page_recs, data.resident_map, cand,
+                                  capacity=capacity, dim=queries.shape[1])
+        return _exact_top_k(queries, vecs, cand, row_query, first_row,
+                            n_rows, k, slots)

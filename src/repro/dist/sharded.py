@@ -234,7 +234,8 @@ class ShardedPageStore:
         fn = self._mesh_fns.get((mesh, sp))
         if fn is None:
             fn, _ = dist.make_sharded_search(
-                mesh, self.cfg, sh.capacity, k=p.k, params=sp
+                mesh, self.cfg, sh.capacity, k=p.k, params=sp,
+                center=sh.data.lsh_center is not None,
             )
             self._mesh_fns[(mesh, sp)] = fn
         with mesh:

@@ -9,12 +9,18 @@ two — the paper's "query threads" as a batching frontend:
 
   * one or more named **collections** register a search backend each
     (``add_collection``); ``submit`` enqueues one query (optionally with
-    its own ``k``/``SearchParams``/``collection``) and returns a future;
-  * requests are grouped by ``(collection, k-bin, params, filter)``: each
-    distinct group fills its own fixed-shape batch, so per-request knobs
-    never force a recompile of an already-warm executable — and requests
-    carrying different filter predicates (static args of the compiled
-    program) never share a dispatch. Per-request ``k`` is
+    its own ``k``/``SearchParams``/``collection``) and returns a future,
+    ``submit_many`` a (Q, d) block of them in one pass;
+  * requests are grouped by ``(collection, k-bin, params, filter
+    group)``: each distinct group fills its own fixed-shape batch, so
+    per-request knobs never force a recompile of an already-warm
+    executable. Filtered requests to a collection that plans its
+    predicates (``PageANNIndex.plan_many``) are planned at submit, in a
+    ``filter.plan`` span, and grouped by their plans' group (predicate
+    shape and path, and the graph path's beam factor): requests whose
+    predicates differ only in value share a dispatch, each carrying its
+    own plan. A backend that takes one predicate per call is grouped by
+    the predicate itself. Per-request ``k`` is
     rounded UP to the engine's ``k_bins`` grid (results trimmed back to
     the requested k), so the number of compiled shapes — and the padding a
     small k pays — stays bounded no matter how many distinct k values
@@ -62,6 +68,7 @@ from typing import Any, Callable, NamedTuple
 import jax
 import numpy as np
 
+from repro.core import plan as plan_mod
 from repro.core.config import SearchParams
 from repro.obs.trace import phase
 from repro.serve.compile_cache import CompileCache, geometry_of, unshared_token
@@ -149,6 +156,20 @@ class EngineMetrics(NamedTuple):
     # backends that report shared_reads
     hop_page_reads: int = 0
     hop_shared_reads: int = 0
+    # filtered search (cumulative): answered requests that carried a
+    # filter, and the dispatches that served them; of the planned ones,
+    # those on the exact path (posting-list scan), their matching rows,
+    # the candidates the device scanned for their dispatches (each
+    # dispatch's packed rows, padding included) and those candidates'
+    # vector bytes; and the matches (M) of every planned request, both
+    # paths
+    filtered_queries: int = 0
+    filtered_dispatches: int = 0
+    posting_queries: int = 0
+    posting_rows: int = 0
+    posting_rows_padded: int = 0
+    posting_bytes: int = 0
+    filter_matches: int = 0
 
 
 class _Pending(NamedTuple):
@@ -161,6 +182,9 @@ class _Pending(NamedTuple):
     # of dispatched (None = wait forever). Expiry applies only while
     # QUEUED: once taken into a batch the request completes normally.
     deadline: float | None = None
+    # the request's filter: its QueryPlan where the backend plans, else
+    # the FilterExpr itself (None: unfiltered)
+    plan: Any = None
 
 
 class _Collection(NamedTuple):
@@ -179,10 +203,15 @@ class _Collection(NamedTuple):
     # () -> {pages_fetched, fetch_hits, fetch_wall_s}; None when the
     # backend has no streaming page tier
     fetch_stats_fn: Callable | None = None
-    # whether search_fn takes a 4th positional arg (a FilterExpr): True
-    # for index-backed collections whose search exposes filter=; raw
+    # whether search_fn takes a 4th positional arg (a FilterExpr, or one
+    # QueryPlan per batch row where plans_fn is set): True for
+    # index-backed collections whose search exposes filter=; raw
     # three-arg closures reject filtered submits up front
     accepts_filter: bool = False
+    # (filters, k, params) -> one QueryPlan (or None) per filter: the
+    # backend's planner (``PageANNIndex.plan_many``); None groups filtered
+    # requests by predicate
+    plans_fn: Callable | None = None
     # QoS dispatch weight: when several groups are due, the one with the
     # highest priority * queue-age dispatches first (weighted aging —
     # high-priority collections win contended slots, low-priority ones
@@ -222,7 +251,7 @@ class BatchingEngine:
         self._clock = clock
         self._lock = threading.RLock()
         self._collections: dict[str, _Collection] = {}
-        # (collection, k_bin, params, filter) -> pending requests of that group
+        # (collection, k_bin, params, filter group) -> pending requests
         self._pending: dict[tuple, list[_Pending]] = {}
         self._timer: threading.Timer | None = None
         self._timer_gen = 0     # invalidates stale timers (see _flush_due)
@@ -249,6 +278,10 @@ class BatchingEngine:
         self._hops_total = 0
         self._hop_page_reads = 0
         self._hop_shared_reads = 0
+        self._filtered = dict.fromkeys((
+            "filtered_queries", "filtered_dispatches", "posting_queries",
+            "posting_rows", "posting_rows_padded", "posting_bytes",
+            "filter_matches"), 0)
         self._sheds = 0
         self._inserts = 0
         self._deletes = 0
@@ -326,14 +359,18 @@ class BatchingEngine:
         if not priority > 0:
             raise ValueError("priority must be > 0")
         accepts_filter = False
+        plans_fn = None
         if index is not None:
             if search_fn is not None:
                 raise ValueError("pass either search_fn or index, not both")
             import inspect
 
-            accepts_filter = "filter" in inspect.signature(
-                index.search
-            ).parameters
+            # a search that names ``filter``, or forwards keywords (a
+            # wrapper of one), takes the per-query plans
+            params_ = inspect.signature(index.search).parameters
+            accepts_filter = "filter" in params_ or any(
+                p.kind is inspect.Parameter.VAR_KEYWORD
+                for p in params_.values())
 
             def search_fn(queries, k_bin, p, flt=None, _index=index,
                           _mesh=mesh):
@@ -344,6 +381,7 @@ class BatchingEngine:
                     kw["filter"] = flt
                 return _index.search(queries, k=k_bin, params=p, **kw)
 
+            plans_fn = getattr(index, "plan_many", None)
             dim = index.dim
             if default_params is None:
                 default_params = getattr(index, "default_params", None)
@@ -390,6 +428,7 @@ class BatchingEngine:
             compact_fn=compact_fn,
             fetch_stats_fn=fetch_stats_fn,
             accepts_filter=accepts_filter,
+            plans_fn=plans_fn if accepts_filter else None,
             priority=priority,
         )
         with self._lock:
@@ -473,11 +512,14 @@ class BatchingEngine:
         """Enqueue one (d,) query; returns a Future[RequestResult].
 
         ``k``/``params`` default to the target collection's; requests
-        sharing a (collection, k-bin, params, filter) group share one
-        fixed-shape dispatch. The filter expression is part of the group
-        key: a batch is a SINGLE backend call, and the predicate is a
-        static argument of its compiled program — two requests with
-        different predicates can never share a dispatch.
+        sharing a (collection, k-bin, params, filter group) share one
+        fixed-shape dispatch. Where the collection plans its predicates,
+        the filter is planned here (a ``filter.plan`` span) and its group
+        is the plan's (``QueryPlan.group``: shape and path, and the graph
+        path's bucket): predicates that differ only in value share a
+        dispatch. Otherwise the group is the predicate itself, one backend
+        call per distinct predicate. A filter the collection's schema does
+        not match raises ``ValueError`` here.
 
         ``deadline_ms`` bounds QUEUE time: a request still pending when
         its deadline passes completes exceptionally with ``TimeoutError``
@@ -485,18 +527,45 @@ class BatchingEngine:
         waiting forever. Once taken into a batch it completes normally —
         the deadline sheds load, it does not cancel dispatched work.
         """
+        return self.submit_many(
+            np.asarray(query, self._dtype).reshape(1, -1), k=k,
+            params=params, collection=collection, filters=[filter],
+            deadline_ms=deadline_ms)[0]
+
+    def submit_many(
+        self,
+        queries: np.ndarray,
+        *,
+        k: int | None = None,
+        params: SearchParams | None = None,
+        collection: str | None = None,
+        filters=None,
+        deadline_ms: float | None = None,
+    ) -> list[Future]:
+        """Enqueue the (Q, d) ``queries``, each with its own filter
+        (``filters``: Q predicates or Nones; None: unfiltered), as
+        :meth:`submit` does one: one Future[RequestResult] each. Their
+        predicates are planned in one pass and they are queued under one
+        lock; nothing is queued if any is refused."""
         if deadline_ms is not None and not deadline_ms > 0:
             raise ValueError("deadline_ms must be > 0")
         col = self._resolve_collection(collection)
-        if filter is not None and not col.accepts_filter:
+        q = np.asarray(queries, self._dtype)
+        if q.ndim != 2:
+            raise ValueError(f"queries must be (Q, d), got shape {q.shape}")
+        n = q.shape[0]
+        filters = [None] * n if filters is None else list(filters)
+        if len(filters) != n:
+            raise ValueError(f"{len(filters)} filters for {n} queries")
+        filtered = any(f is not None for f in filters)
+        if filtered and not col.accepts_filter:
             raise ValueError(
                 f"collection {col.name!r} does not support filtered "
                 "search (raw search_fn backends take no filter)"
             )
-        q = np.asarray(query, self._dtype).reshape(-1)
-        if q.shape[0] != col.dim:
+        if q.shape[1] != col.dim:
             raise ValueError(
-                f"query dim {q.shape[0]} != collection {col.name!r} dim "
+                f"query dim {q.shape[1]} != collection {col.name!r} dim "
                 f"{col.dim}"
             )
         if k is None:
@@ -507,10 +576,19 @@ class BatchingEngine:
         if k < 1:
             raise ValueError("k must be >= 1")
         params = params if params is not None else col.default_params
-        key = (col.name, self._bin_k(k), params, filter)
-        fut: Future = Future()
-        batch = None
+        k_bin = self._bin_k(k)
         tr = self._tracer
+        plans = filters
+        if filtered and col.plans_fn is not None:
+            with phase("filter.plan", tr, clock=self._clock, cat="engine",
+                       track="engine", collection=col.name, n=n):
+                plans = col.plans_fn(filters, k_bin, params)
+            groups = [None if pl is None else ("plan",) + pl.group
+                      for pl in plans]
+        else:
+            groups = [None if f is None else ("filter", f) for f in filters]
+        futs = [Future() for _ in range(n)]
+        batches, shed = [], []
         with self._lock:
             if self._closed:
                 raise RuntimeError("engine is closed")
@@ -519,30 +597,35 @@ class BatchingEngine:
                 # collection: refuse rather than strand the future in a
                 # group nothing will ever dispatch
                 raise KeyError(f"no collection {col.name!r}")
-            if self._t_first is None:
-                self._t_first = self._clock()
-            self._rid += 1
-            rid = self._rid
             t_submit = self._clock()
+            if self._t_first is None:
+                self._t_first = t_submit
             deadline = (
                 t_submit + deadline_ms / 1e3 if deadline_ms is not None
                 else None
             )
-            group = self._pending.setdefault(key, [])
-            group.append(_Pending(fut, q, k, t_submit, rid, deadline))
-            if len(group) >= self._batch_size:
-                batch, shed = self._take_locked(key)
-            else:
-                shed = ()
-                self._arm_timer_locked()
+            rid0 = self._rid + 1
+            self._rid += n
+            for i, (fut, plan, group) in enumerate(zip(futs, plans, groups)):
+                key = (col.name, k_bin, params, group)
+                pending = self._pending.setdefault(key, [])
+                pending.append(_Pending(fut, q[i], k, t_submit, rid0 + i,
+                                        deadline, plan))
+                if len(pending) >= self._batch_size:
+                    batch, dropped = self._take_locked(key)
+                    shed += dropped
+                    if batch is not None:
+                        batches.append((key, batch))
+            self._arm_timer_locked()
         if tr is not None and tr.enabled:
-            tr.add("submit", t_submit, t_submit, cat="request",
-                   track=f"req-{rid}",
-                   args={"collection": col.name, "k": k})
+            for i in range(n):
+                tr.add("submit", t_submit, t_submit, cat="request",
+                       track=f"req-{rid0 + i}",
+                       args={"collection": col.name, "k": k})
         self._fail_shed(shed)
-        if batch is not None:
+        for key, batch in batches:
             self._run_batch(key, batch)
-        return fut
+        return futs
 
     def flush(self, collection: str | None = None) -> None:
         """Dispatch whatever is pending — in every group, or only the named
@@ -585,12 +668,17 @@ class BatchingEngine:
         collection: str | None = None,
         filter=None,
     ) -> list[RequestResult]:
-        """Synchronous convenience: submit a (Q, d) batch, flush, gather."""
+        """Synchronous convenience: submit a (Q, d) batch, flush, gather.
+        ``filter`` is one predicate for every query or a sequence of one
+        per query."""
+        queries = np.asarray(queries)
+        filters = (list(filter) if isinstance(filter, (list, tuple))
+                   else [filter] * len(queries))
         futs = [
             self.submit(
-                q, k=k, params=params, collection=collection, filter=filter
+                q, k=k, params=params, collection=collection, filter=f
             )
-            for q in np.asarray(queries)
+            for q, f in zip(queries, filters)
         ]
         self.flush(collection=collection)
         return [f.result() for f in futs]
@@ -867,7 +955,7 @@ class BatchingEngine:
 
     def _run_batch(self, key: tuple, batch: tuple[int, list[_Pending]]) -> None:
         """Pad, search (outside the lock), record counters, demux."""
-        name, k_bin, params, flt = key
+        name, k_bin, params, group = key
         batch_index, take = batch
         n = len(take)
         tr = self._tracer
@@ -906,8 +994,16 @@ class BatchingEngine:
                 resolved = (k_bin, params)
             warm = self._compile_cache.note(
                 col.geometry + (self._batch_size, resolved)
-                + ((("filter", flt),) if flt is not None else ())
+                + ((("filter", group),) if group is not None else ())
             )
+            if group is None:
+                args = (padded, k_bin, params)
+            elif group[0] == "plan":
+                # one plan per row; a pad row's None asks for no answer
+                args = (padded, k_bin, params, [p.plan for p in take]
+                        + [None] * (self._batch_size - n))
+            else:
+                args = (padded, k_bin, params, take[0].plan)
         if tracing:
             for p in take:
                 tr.add("queue_wait", p.t_submit, assemble.t0, cat="request",
@@ -918,12 +1014,7 @@ class BatchingEngine:
             with phase("engine.dispatch", tr, clock=self._clock,
                        cat="engine", track="engine", collection=name,
                        batch_index=batch_index, n=n, compiled=not warm):
-                out = (
-                    col.search_fn(padded, k_bin, params, flt)
-                    if col.accepts_filter
-                    else col.search_fn(padded, k_bin, params)
-                )
-                out = jax.tree.map(np.asarray, out)
+                out = jax.tree.map(np.asarray, col.search_fn(*args))
         except Exception as e:
             # a backend failure must reach every waiter of THIS group
             # through its future — not hang them, not vanish into the timer
@@ -940,7 +1031,7 @@ class BatchingEngine:
         with phase("engine.demux", tr, clock=self._clock, cat="engine",
                    track="engine", batch_index=batch_index, n=n) as demux:
             latencies = self._demux(out, take, n, k_bin, batch_index,
-                                    resolved, t_done)
+                                    resolved, t_done, col.dim)
         if tracing:
             for p, latency_ms in zip(take, latencies):
                 tr.add("request", p.t_submit, demux.t1, cat="request",
@@ -949,9 +1040,11 @@ class BatchingEngine:
                              "batch_index": batch_index})
 
     def _demux(self, out, take: list[_Pending], n: int, k_bin: int,
-               batch_index: int, resolved, t_done: float) -> list[float]:
+               batch_index: int, resolved, t_done: float,
+               dim: int) -> list[float]:
         """Record the dispatch's counters and hand each request its row;
         returns the requests' latencies (ms)."""
+        filtered = self._filter_counts(take, k_bin, dim)
         ios = getattr(out, "ios", None)
         hops = getattr(out, "hops", None)
         hits = getattr(out, "cache_hits", None)
@@ -979,8 +1072,14 @@ class BatchingEngine:
             if shared is not None:          # a SearchResult with its trail
                 self._hop_page_reads += int(np.sum(ios[:n]) + np.sum(hits[:n]))
                 self._hop_shared_reads += int(np.sum(shared[:n]))
+            for name, v in filtered.items():
+                self._filtered[name] += v
+        # a NamedTuple of arrays (SearchResult) splits without a tree walk
+        plain = hasattr(out, "_fields") and all(
+            a is None or isinstance(a, np.ndarray) for a in out)
         for i, p in enumerate(take):
-            row = jax.tree.map(lambda a: a[i], out)
+            row = (out._make(None if a is None else a[i] for a in out)
+                   if plain else jax.tree.map(lambda a: a[i], out))
             if p.k < k_bin:
                 # k was rounded up to the bin: trim the result axes back
                 row = jax.tree.map(
@@ -998,6 +1097,25 @@ class BatchingEngine:
                 )
             )
         return latencies
+
+    def _filter_counts(self, take: list[_Pending], k_bin: int,
+                       dim: int) -> dict:
+        """The filtered-search counters one dispatch of ``take`` adds."""
+        first = take[0].plan
+        if first is None:
+            return {}
+        out = {"filtered_queries": len(take), "filtered_dispatches": 1}
+        if not hasattr(first, "path"):     # a predicate, not a plan
+            return out
+        out["filter_matches"] = sum(p.plan.count for p in take)
+        if first.path == plan_mod.EXACT:
+            padded = (plan_mod.packed_rows(sum(p.plan.bucket for p in take))
+                      * plan_mod.row_width(k_bin))
+            out.update(posting_queries=len(take),
+                       posting_rows=out["filter_matches"],
+                       posting_rows_padded=padded,
+                       posting_bytes=padded * dim * 4)
+        return out
 
     # -------------------------------------------------------------- metrics
     def metrics(self) -> EngineMetrics:
@@ -1073,6 +1191,7 @@ class BatchingEngine:
                 hops_total=self._hops_total,
                 hop_page_reads=self._hop_page_reads,
                 hop_shared_reads=self._hop_shared_reads,
+                **self._filtered,
             )
 
     def metrics_windows(self) -> dict:

@@ -28,8 +28,13 @@ dispatch. Every decision is visible in the exposition —
 ``pageann_http_rejected_total{reason=}`` ride the same registry as the
 engine series. No third-party dependencies.
 
-A ``/search`` request's decode (body read, ``json.loads``, the query
-matrix) is the ``http.decode`` span of :func:`repro.obs.trace.phase`, and
+A ``/search`` request may carry a predicate per query, ``"filters"``
+beside ``"queries"`` (one object each), or one ``"filter"`` for every
+query, in the JSON form ``repro.core.filter.parse_filter`` reads, such as
+``{"labels": {"all": ["beach", "sunset"]}}``; a predicate that names a
+field the collection's schema lacks is a 400. A ``/search`` request's
+decode (body read, ``json.loads``, the query matrix, the predicates) is
+the ``http.decode`` span of :func:`repro.obs.trace.phase`, and
 the encode of every routed reply (the results and ``json.dumps``) is an
 ``http.encode`` span, each tagged with the frontend's request id: they
 land in any ``jax.profiler`` trace, and in the ring buffer of the
@@ -37,6 +42,7 @@ service's tracer when it has one.
 """
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import threading
@@ -45,6 +51,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 import numpy as np
 
+from repro.core.filter import parse_filter
 from repro.obs.server import PROMETHEUS_CONTENT_TYPE, _jsonable
 from repro.obs.trace import phase
 
@@ -260,6 +267,13 @@ class HttpFrontend:
                     return
                 self._dispatch(lambda rid: fn(self._body, rid))
 
+        # what the process loaded before it serves (indexes, vocabularies,
+        # compiled programs) lives as long as the server: move it out of
+        # the collector's generations, so a full collection, which would
+        # rescan all of it while holding the interpreter for tens of
+        # milliseconds, sees only what serving allocates since
+        gc.collect()
+        gc.freeze()
         self._httpd = ThreadingHTTPServer((host, port), Handler)
         self._httpd.daemon_threads = True
         self._thread = threading.Thread(
@@ -324,28 +338,28 @@ class HttpFrontend:
                 q = np.asarray(queries, np.float32)
             except (TypeError, ValueError) as e:
                 raise _RequestError(400, f"bad query payload: {e}")
-        if q.ndim != 2 or q.shape[0] == 0:
-            raise _RequestError(
-                400, f"queries must be a non-empty (Q, d) matrix, "
-                     f"got shape {q.shape}"
-            )
+            if q.ndim != 2 or q.shape[0] == 0:
+                raise _RequestError(
+                    400, f"queries must be a non-empty (Q, d) matrix, "
+                         f"got shape {q.shape}"
+                )
+            filters = self._filters_of(doc, len(q))
         k = doc.get("k")
         deadline_ms = doc.get("deadline_ms", self._default_deadline_ms)
         release = self._admit(name)
         try:
             t0 = time.perf_counter()
             try:
-                futs = [
-                    self._service.submit(
-                        name, row, k=k, deadline_ms=deadline_ms
-                    )
-                    for row in q
-                ]
-                self._service.flush(name)
+                futs = self._service.submit_many(
+                    name, q, k=k, filters=filters, deadline_ms=deadline_ms)
             except KeyError:
                 raise _RequestError(404, f"no collection {name!r}")
             except ValueError as e:
                 raise _RequestError(400, str(e))
+            finally:
+                # what was queued before a refusal is dispatched all the
+                # same, so no request is left waiting on a flush
+                self._service.flush(name)
             results = []
             shed = 0
             for fut in futs:
@@ -377,6 +391,23 @@ class HttpFrontend:
             return 200, doc_out, {}
         finally:
             release()
+
+    @staticmethod
+    def _filters_of(doc: dict, n: int) -> list:
+        """One predicate (or None) per query: ``"filters"``, a list of
+        ``n``, or ``"filter"``, one for every query."""
+        if "filters" in doc:
+            raw = doc["filters"]
+            if not isinstance(raw, list) or len(raw) != n:
+                raise _RequestError(
+                    400, f"'filters' must be a list of {n} filters, one "
+                         "per query")
+        else:
+            raw = [doc.get("filter")] * n
+        try:
+            return [None if f is None else parse_filter(f) for f in raw]
+        except ValueError as e:
+            raise _RequestError(400, f"bad filter: {e}")
 
     def _handle_insert(self, read_body, _rid: int):
         doc = read_body()
@@ -434,6 +465,8 @@ class HttpFrontend:
         self._httpd.shutdown()
         self._thread.join(timeout=5.0)
         self._httpd.server_close()
+        # what the start froze goes back to the collector
+        gc.unfreeze()
 
     def __enter__(self):
         return self

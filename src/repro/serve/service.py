@@ -8,7 +8,8 @@ is the database surface. A :class:`VectorService` owns
     registered on
   * one shared :class:`repro.serve.engine.BatchingEngine` core — a single
     batching/timer/demux loop whose pending groups are keyed by
-    ``(collection, k-bin, params)``, so every collection gets fixed-shape
+    ``(collection, k-bin, params, filter group)``, so every collection
+    gets fixed-shape
     dispatches without its own process, its own metrics machinery, or its
     own timer thread, and
   * one shared :class:`repro.serve.compile_cache.CompileCache` — compiled
@@ -318,8 +319,10 @@ class VectorService:
     ):
         """Enqueue one query for ``collection``; returns a
         Future[RequestResult]. Requests sharing a (collection, k-bin,
-        params, filter) group share one fixed-shape dispatch on the common
-        core. ``deadline_ms`` bounds queue time (see
+        params, filter group) share one fixed-shape dispatch on the common
+        core: predicates of one shape planned to one path and bucket share
+        it whatever their values (see ``BatchingEngine.submit``).
+        ``deadline_ms`` bounds queue time (see
         ``BatchingEngine.submit``); a semantic-cache hit resolves
         immediately and never expires.
 
@@ -365,6 +368,29 @@ class VectorService:
         fut.add_done_callback(_store)
         return fut
 
+    def submit_many(
+        self,
+        collection: str,
+        queries: np.ndarray,
+        *,
+        k: int | None = None,
+        params: SearchParams | None = None,
+        filters=None,
+        deadline_ms: float | None = None,
+    ) -> list:
+        """:meth:`submit` of each row of ``queries`` (``filters``: one per
+        row, or None), planned and queued in one pass
+        (``BatchingEngine.submit_many``); with a semantic cache, one by
+        one, each through the cache."""
+        if self._semantic_cache is None:
+            return self._engine.submit_many(
+                queries, k=k, params=params, collection=collection,
+                filters=filters, deadline_ms=deadline_ms)
+        filters = [None] * len(queries) if filters is None else filters
+        return [self.submit(collection, q, k=k, params=params, filter=f,
+                            deadline_ms=deadline_ms)
+                for q, f in zip(queries, filters)]
+
     def search(
         self,
         collection: str,
@@ -375,10 +401,15 @@ class VectorService:
         filter=None,
     ) -> list[RequestResult]:
         """Synchronous convenience: submit a (Q, d) batch, flush, gather.
-        Routed through :meth:`submit` so the semantic cache applies."""
+        Routed through :meth:`submit` so the semantic cache applies.
+        ``filter`` is one predicate for every query or a sequence of one
+        per query."""
+        queries = np.asarray(queries)
+        filters = (list(filter) if isinstance(filter, (list, tuple))
+                   else [filter] * len(queries))
         futs = [
-            self.submit(collection, q, k=k, params=params, filter=filter)
-            for q in np.asarray(queries)
+            self.submit(collection, q, k=k, params=params, filter=f)
+            for q, f in zip(queries, filters)
         ]
         self._engine.flush(collection=collection)
         return [f.result() for f in futs]

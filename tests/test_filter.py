@@ -1,7 +1,7 @@
 """Filtered search: schema/expression validation, in-scan masking recall
 parity vs a post-filter brute force across MemoryModes + the streamed
-tier, persistence round-trips, mutable-tier filtering, and engine group
-keying by filter."""
+tier, persistence round-trips, mutable-tier filtering, and engine
+grouping of predicates that differ only in value."""
 import json
 import math
 import os
@@ -334,21 +334,22 @@ def test_mutable_save_load_round_trips_metadata(dataset, tmp_path):
 
 # ------------------------------------------------------- engine (satellite)
 def test_engine_groups_by_filter_and_matches_direct_search(indexes, dataset):
+    """Predicates of one shape planned to one path and bucket carry their
+    values as data: three distinct predicates share ONE dispatch, and each
+    request still gets its own predicate's answer."""
     _, q, _ = dataset
     idx = indexes[MemoryMode.HYBRID]
-    en, de = Tag("lang") == "en", Tag("lang") == "de"
+    preds = [Tag("lang") == v for v in ("en", "de", "fr")]
+    assert len({idx.plan(f).group for f in preds}) == 1
     with BatchingEngine.from_index(idx, k=K, batch_size=64) as eng:
-        futs = (
-            [eng.submit(v, filter=en) for v in q]
-            + [eng.submit(v, filter=de) for v in q]
-            + [eng.submit(v) for v in q]
-        )
+        futs = [eng.submit(v, filter=f) for f in preds for v in q]
         eng.flush()
         rows = [f.result() for f in futs]
-        # three distinct pending groups -> three dispatches, even though
-        # one 64-wide batch could hold all 24 requests
-        assert eng.metrics().batches == 3
-    for flt, chunk in zip((en, de, None), range(3)):
+        # 3 predicates of one shape -> 1 dispatch of 24 requests
+        m = eng.metrics()
+        assert m.batches == 1
+        assert (m.filtered_queries, m.filtered_dispatches) == (3 * Q, 1)
+    for chunk, flt in enumerate(preds):
         got = np.stack(
             [r.result.ids for r in rows[chunk * Q:(chunk + 1) * Q]]
         )

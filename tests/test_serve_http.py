@@ -1,6 +1,7 @@
 """HTTP frontend contracts: endpoint surface over a live socket,
 admission control (in-flight 503, token-bucket 429, deadline 504),
 validation errors, and the shared metrics exposition."""
+import gc
 import json
 import urllib.error
 import urllib.request
@@ -9,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro.core import MemoryMode, PageANNConfig, PageANNIndex
+from repro.core.filter import parse_filter
 from repro.core.vamana import brute_force_knn
 from repro.data.pipeline import clustered_vectors, query_vectors
 from repro.obs import parse_prometheus_text, sample_value
@@ -241,3 +243,88 @@ def test_token_bucket_validation():
         TokenBucket(rate=0.0, burst=1.0)
     with pytest.raises(ValueError):
         TokenBucket(rate=1.0, burst=0.0)
+
+
+# ---------------------------------------------------------- filtered search
+@pytest.fixture(scope="module")
+def tagged(corpus):
+    """The corpus with a bag of tags per vector: vector i holds "a" when
+    i % 2 == 0, "b" when i % 3 == 0 and "c" when i % 5 == 0."""
+    from repro.core import MetadataSchema
+
+    bags = [[t for t, m in (("a", 2), ("b", 3), ("c", 5)) if i % m == 0]
+            for i in range(N)]
+    cfg = PageANNConfig(
+        dim=D, graph_degree=12, build_beam=24, pq_subspaces=8,
+        lsh_sample=256, lsh_entries=8, beam_width=48, max_hops=48,
+        memory_mode=MemoryMode.HYBRID,
+    )
+    return PageANNIndex.build(corpus, cfg,
+                              schema=MetadataSchema(bags={"labels": 3}),
+                              metadata={"labels": bags})
+
+
+def test_search_with_per_query_filters_serves_passing_ids(tagged, corpus):
+    q = query_vectors(corpus, 4, seed=5)
+    filters = [{"labels": {"all": t}} for t in
+               (["a"], ["a", "b"], ["b", "c"], ["c"])]
+    with VectorService(batch_size=16) as svc:
+        svc.create_collection("tagged", tagged, k=K)
+        with HttpFrontend(svc, port=0) as fe:
+            code, doc, _ = _post(fe.url + "/search", {
+                "collection": "tagged", "queries": q.tolist(), "k": K,
+                "filters": filters,
+            })
+            assert code == 200, doc
+            one, single, _ = _post(fe.url + "/search", {
+                "collection": "tagged", "query": q[1].tolist(), "k": K,
+                "filter": filters[1],
+            })
+            # the batch dispatches once per plan group its predicates
+            # fall in, the single query once more
+            groups = {tagged.plan(parse_filter(f), k=K).group
+                      for f in filters}
+            assert svc.metrics().filtered_dispatches == len(groups) + 1
+    assert one == 200
+    assert single["results"]["ids"] == doc["results"][1]["ids"]
+    for i, (r, m) in enumerate(zip(doc["results"], (2, 6, 15, 5))):
+        ids = np.array(r["ids"])
+        assert (ids >= 0).all() and (ids % m == 0).all()
+        # the exact K nearest of the passing rows, nearest first
+        rows = np.arange(0, N, m)
+        d = ((corpus[rows].astype(np.float64) - q[i]) ** 2).sum(-1)
+        np.testing.assert_array_equal(ids, rows[np.argsort(d)[:K]])
+
+
+def test_search_with_an_unknown_filter_field_is_400(tagged, corpus):
+    with VectorService(batch_size=16) as svc:
+        svc.create_collection("tagged", tagged, k=K)
+        with HttpFrontend(svc, port=0) as fe:
+            for doc in (
+                {"query": corpus[0].tolist(),
+                 "filter": {"colour": {"all": ["red"]}}},
+                {"queries": corpus[:2].tolist(),
+                 "filters": [{"labels": {"all": ["a"]}}]},   # one for two
+                {"query": corpus[0].tolist(),
+                 "filter": {"labels": {"near": ["a"]}}},
+            ):
+                code, body, _ = _post(fe.url + "/search",
+                                      dict(doc, collection="tagged", k=K))
+                assert code == 400, body
+            code, body, _ = _post(fe.url + "/search", {
+                "collection": "tagged", "k": K, "query": corpus[0].tolist(),
+                "filter": {"colour": {"all": ["red"]}}})
+            assert "unknown bag field 'colour'" in body["error"]
+            assert svc.metrics().requests == 0
+
+
+def test_the_frontend_freezes_what_was_loaded_while_it_serves(index):
+    """What is alive when a frontend starts (the attached index among it)
+    is out of the collector's generations while it serves, so a full
+    collection does not rescan it, and back in them once it closes."""
+    gc.unfreeze()
+    with VectorService(batch_size=16) as svc:
+        svc.create_collection("wiki", index, k=K)
+        with HttpFrontend(svc, port=0):
+            assert gc.get_freeze_count() > 0
+        assert gc.get_freeze_count() == 0

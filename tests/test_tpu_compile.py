@@ -161,3 +161,64 @@ def test_batch_search_executable_compiles(one_chip, monkeypatch):
     # the beam-membership probe is one dense compare: no serial loop (a
     # searchsorted binary search lowers to a while loop of gathers)
     assert not [s for s in scopes if re.search(r"hop_cand_probe/.*while", s)]
+
+
+def test_filtered_search_executables_compile(one_chip, monkeypatch):
+    """The filtered deployment's two programs at d = 192: the graph path
+    (``GRAPH_LANES`` queries a program) with a bag predicate's values
+    vmapped beside the queries and the beam widened four times, and the
+    exact path's posting scan at its widest packing: 64 queries of up to
+    5,120 candidates each, 16,384 rows of 32."""
+    from repro.core import Bag, MemoryMode, MetadataSchema, PageANNConfig
+    from repro.core import PageANNIndex
+    from repro.core import search as search_mod
+    from repro.core.plan import GRAPH_LANES
+    from repro.data.pipeline import clustered_vectors
+    from repro.kernels import ops
+
+    d, batch = 192, 64
+    x = clustered_vectors(400, d, num_clusters=8, seed=0)
+    cfg = PageANNConfig(
+        dim=d, graph_degree=16, build_beam=24, pq_subspaces=16,
+        lsh_sample=256, memory_mode=MemoryMode.HYBRID,
+    )
+    index = PageANNIndex.build(
+        x, cfg, schema=MetadataSchema(bags={"labels": 32}),
+        metadata={"labels": [["t0", "t1"]] * len(x)})
+    cf = index.plan(Bag("labels").contains_all("t0", "t1")).filter
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(np.shape(a), jnp.asarray(a).dtype,
+                                    sharding=one_chip)
+
+    data, meta = jax.tree.map(sds, (index.data, index.meta))
+    q = jax.ShapeDtypeStruct((batch, d), jnp.float32, sharding=one_chip)
+    lanes = jax.ShapeDtypeStruct((GRAPH_LANES, d), jnp.float32,
+                                 sharding=one_chip)
+    vals = search_mod.FilterValues(
+        codes=jax.ShapeDtypeStruct((GRAPH_LANES, cf.shape.n_codes),
+                                   jnp.int32, sharding=one_chip),
+        bounds=jax.ShapeDtypeStruct((GRAPH_LANES, 0, 2), jnp.float32,
+                                    sharding=one_chip))
+    rows, width, slots = 16384, 32, 256
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    p = index.default_params
+    try:
+        graph = search_mod.batch_search.lower(
+            lanes, data, p.replace(beam_width=4 * p.beam_width),
+            capacity=index.store.capacity, mode=cfg.memory_mode.value,
+            meta=meta, fshape=cf.shape, fvals=vals,
+        ).compile().as_text()
+        exact = search_mod.posting_scan.lower(
+            q, ints(rows, width), ints(rows), ints(batch), ints(batch), data,
+            k=10, slots=slots, capacity=index.store.capacity,
+        ).compile().as_text()
+    finally:
+        jax.clear_caches()
+    scopes = " ".join(re.findall(r'op_name="([^"]*)"', graph))
+    assert "hop_filter" in scopes and "tpu_custom_call" in graph
+    assert "posting_scan" in " ".join(re.findall(r'op_name="([^"]*)"', exact))
