@@ -3,7 +3,9 @@
 Per query the loop maintains a :class:`BeamState`:
   * a candidate set (size-L, distance-sorted, visited flags) over *vector*
     ids in the reassigned space (page = id // capacity),
-  * a visited-page bitmap (the paper's visited set V),
+  * the trail of pages scheduled at each hop, (max_hops, b) and PAD
+    padded: the paper's visited set V, whose size the search's knobs fix
+    whatever the corpus's,
   * a running exact-distance result set (size-K),
 and per hop applies three pure transition functions:
 
@@ -24,12 +26,15 @@ and per hop applies three pure transition functions:
                         top-k via ``jax.lax.top_k`` — no full sorts.
 
 The hot loop is argsort-free: merges use ``lax.top_k``, batch-local dedup
-is one ``lax.sort`` + segment-boundary mask, and the beam-membership test
-of a hop's b*Rp neighbour ids is one dense (b*Rp, L) equality compare
-(``in_beam``). That is O(b*Rp*L) compares, a few microseconds of vector
-work, where a sorted ``searchsorted`` probe lowers to a ``while`` loop of
-dependent gathers: on a TPU v5e that loop held 35% of the resident
-search's device time (io batch 5, 48 neighbour slots a page, beam 96).
+is one ``lax.sort`` + segment-boundary mask, and every membership test is
+one dense equality compare (``dense_isin``): a hop's b*Rp neighbour ids
+against the (L,) beam, and the pages of the beam's candidates and of the
+hop's neighbours against the trail. That is O(b*Rp*(L + H*b)) compares, a
+few microseconds of vector work, where a sorted ``searchsorted`` probe
+lowers to a ``while`` loop of dependent gathers (on a TPU v5e it held 35%
+of the resident search's device time: io batch 5, 48 neighbour slots a
+page, beam 96) and a per-lane (P,) bitmap to element gathers and scatters
+that grow with the corpus (19% there).
 
 Everything is fixed-shape: the loop is a ``lax.while_loop``, queries are
 vmapped (``batch_search``) and optionally sharded over a device mesh
@@ -51,7 +56,7 @@ hop's pages in ``hop_filter``. ``batch_search`` also keeps each lane's scheduled
 pages per hop and reports, per query, the reads of a page that a
 lower-numbered lane scanned at the same hop (``SearchResult.shared_reads``):
 what a hop kernel that reads each shared page once would save. That
-bookkeeping runs under a scope of its own, ``shared_reads``.
+count runs under a scope of its own, ``shared_reads``.
 
 Filtered search carries its predicate as data: the static
 :class:`repro.core.filter.FilterShape` keys the program, and each query's
@@ -164,13 +169,19 @@ class BeamState(NamedTuple):
     The two adaptive fields are ``None`` — absent from the pytree — unless
     per-query early termination is on (``AdaptiveParams.patience``), so the
     non-adaptive loop carries the exact pre-adaptive structure and compiles
-    to the same program. ``trail`` is carried by ``_search_one`` alone.
+    to the same program.
+
+    ``trail`` is the visited set V: row h holds the pages scheduled at hop
+    h, and the rows from ``hops`` on are PAD, which no page id matches. A
+    page is visited exactly when it is in the trail, so each visited test
+    is one dense compare against it, and nothing in the state grows with
+    the page count.
     """
 
     cand_ids: jnp.ndarray   # (L,) candidate vector ids, PAD padded
     cand_d: jnp.ndarray     # (L,) estimated distances, INF padded
     cand_vis: jnp.ndarray   # (L,) expanded/scheduled flags
-    page_vis: jnp.ndarray   # (P,) visited-page bitmap (the paper's V)
+    trail: jnp.ndarray      # (max_hops, b) pages scheduled per hop, PAD padded
     res_ids: jnp.ndarray    # (k,) running exact top-k ids
     res_d: jnp.ndarray      # (k,) running exact top-k distances
     io: jnp.ndarray         # () page reads served from 'disk'
@@ -181,8 +192,6 @@ class BeamState(NamedTuple):
     # hops failed to improve it by more than epsilon
     frontier: jnp.ndarray | None = None   # () f32
     stall: jnp.ndarray | None = None      # () int32 patience counter
-    # (max_hops, b) int32: the pages scheduled at each hop, PAD padded
-    trail: jnp.ndarray | None = None
 
 
 def _mask_dups_keep_first(ids: jnp.ndarray, d: jnp.ndarray) -> jnp.ndarray:
@@ -245,6 +254,8 @@ def init_state(
     beam: int,
     k: int,
     entries: int,
+    max_hops: int,
+    io_batch: int,
     entry_slack: int | None = None,
     min_entries: int = 1,
     patience: int | None = None,
@@ -261,7 +272,6 @@ def init_state(
     keeps the whole top-T. Fixed-shape and vmap-safe: dropped candidates
     are masked to PAD/INF in place, never compacted.
     """
-    num_pages = data.resident_map.shape[0]
     qh = q if data.lsh_center is None else q - data.lsh_center
     qcode = hash_codes(qh[None], data.lsh_planes)[0]
     ham = ops.hamming(data.lsh_codes, qcode)
@@ -282,7 +292,7 @@ def init_state(
         cand_ids=cand_ids,
         cand_d=cand_d,
         cand_vis=jnp.zeros((beam,), bool),
-        page_vis=jnp.zeros((num_pages,), bool),
+        trail=jnp.full((max_hops, io_batch), PAD, jnp.int32),
         res_ids=jnp.full((k,), PAD, jnp.int32),
         res_d=jnp.full((k,), INF, jnp.float32),
         io=jnp.int32(0),
@@ -303,18 +313,22 @@ def select_batch(
     stable-sort the beam by (masked distance, slot), keep the first
     occurrence of each page among finite entries, and take the first b —
     exactly the pages the iterated argmin would have scheduled, in the same
-    order. Returns the updated state (selected candidates expanded, their
-    pages marked visited, candidates on stale pages retired) and the (b,)
-    batch of page ids to read, PAD padded.
+    order. Returns the updated state (selected candidates expanded, the
+    batch written to the trail's row ``hops``, candidates on stale pages
+    retired) and the (b,) batch of page ids to read, PAD padded.
+
+    Both visited tests are dense compares of the candidates' pages: a page
+    is stale when the trail holds it (its rows from this hop on are still
+    PAD), and co-page candidates are expanded where the batch's first b-1
+    pages hold it.
     """
     cand_ids = state.cand_ids
     beam = cand_ids.shape[0]
-    num_pages = state.page_vis.shape[0]
     b = io_batch
 
     cpages = jnp.where(cand_ids >= 0, cand_ids // capacity, 0)
     # retire candidates whose page was visited before this hop
-    stale = (cand_ids != PAD) & state.page_vis[cpages]
+    stale = (cand_ids != PAD) & dense_isin(cpages, state.trail)
     masked = jnp.where(
         state.cand_vis | stale | (cand_ids == PAD), INF, state.cand_d
     )
@@ -339,32 +353,29 @@ def select_batch(
         .at[jnp.where(scheduled, rank, b)]
         .set(spages.astype(jnp.int32), mode="drop")
     )
-    page_vis = state.page_vis.at[
-        jnp.where(scheduled, spages, num_pages)
-    ].set(True, mode="drop")
-
     # expanded flags: the b scheduled picks, plus co-page candidates of any
     # page scheduled before the final pick (the serial loop's stale marking
-    # ran once more after each pick except the last)
-    early_pv = (
-        jnp.zeros_like(state.page_vis)
-        .at[jnp.where(scheduled & (rank < b - 1), spages, num_pages)]
-        .set(True, mode="drop")
-    )
+    # ran once more after each pick except the last); the batch is in rank
+    # order and its PAD slots match no page
     cand_vis = state.cand_vis | stale
     cand_vis = cand_vis.at[jnp.where(scheduled, sslot, beam)].set(
         True, mode="drop"
     )
-    cand_vis = cand_vis | ((cand_ids != PAD) & early_pv[cpages])
+    early = dense_isin(cpages, batch[:b - 1])
+    cand_vis = cand_vis | ((cand_ids != PAD) & early)
     # the serial argmin marked slot 0 on every exhausted pick (all-INF mask)
     cand_vis = cand_vis.at[0].set(cand_vis[0] | (n_sched < b))
-    return state._replace(cand_vis=cand_vis, page_vis=page_vis), batch
+    # a select, not a scatter: cheaper in the vmapped loop
+    at_hop = jnp.arange(state.trail.shape[0])[:, None] == state.hops
+    trail = jnp.where(at_hop, batch[None, :], state.trail)
+    return state._replace(cand_vis=cand_vis, trail=trail), batch
 
 
-def in_beam(nids: jnp.ndarray, cand_ids: jnp.ndarray) -> jnp.ndarray:
-    """(n,) membership of each neighbour id in the (L,) beam, PAD included:
-    one (n, L) equality compare reduced over L, with no loop to lower."""
-    return (nids[:, None] == cand_ids[None, :]).any(-1)
+def dense_isin(ids: jnp.ndarray, pool: jnp.ndarray) -> jnp.ndarray:
+    """(n,) membership of each id in ``pool`` (any shape), PAD included:
+    one (n, pool.size) equality compare reduced over the pool, with no loop
+    to lower and no gather."""
+    return (ids[:, None] == pool.reshape(1, -1)).any(-1)
 
 
 @jax.named_scope("hop_filter")
@@ -437,6 +448,10 @@ def score_page_batch(
     remain traversable through filtered-out regions to reach passing
     ones. With no filter both are ``None`` and the traced program is the
     exact pre-filter one.
+
+    A neighbour is skipped where its page is in ``state.trail``, this
+    hop's row included (one dense (b*Rp, H*b) compare), or its id in the
+    beam, or it repeats an earlier neighbour of the hop.
 
     Returns (member_ids, member_dists) flattened to (b*cap,),
     (neighbor_ids, estimated_dists) flattened to (b*Rp,) and INF-masked,
@@ -521,11 +536,12 @@ def score_page_batch(
                 data.mem_mask[safe_nids], est_mem, est_disk.reshape(-1)
             )
         est = jnp.where(valid_n, est, INF)
-        # skip neighbors on already-visited pages
-        est = jnp.where(state.page_vis[safe_nids // capacity], INF, est)
-    # skip neighbors already in the candidate set: one dense compare
+        # skip neighbors on visited pages, this hop's included
+        est = jnp.where(dense_isin(safe_nids // capacity, state.trail), INF,
+                        est)
+    # skip neighbors already in the candidate set
     with jax.named_scope("hop_cand_probe"):
-        est = jnp.where(in_beam(flat_nids, state.cand_ids), INF, est)
+        est = jnp.where(dense_isin(flat_nids, state.cand_ids), INF, est)
     # dedupe within this batch
     with jax.named_scope("hop_dedupe"):
         est = _mask_dups_keep_first(flat_nids, est)
@@ -623,8 +639,9 @@ def _search_one(
     )
     state = init_state(
         q, data, disk_lut, beam=beam, k=k, entries=entries,
-        entry_slack=entry_slack, min_entries=min_entries, patience=patience,
-    )._replace(trail=jnp.full((max_hops, io_batch), PAD, jnp.int32))
+        max_hops=max_hops, io_batch=io_batch, entry_slack=entry_slack,
+        min_entries=min_entries, patience=patience,
+    )
 
     def cond(state: BeamState):
         live = (
@@ -644,11 +661,6 @@ def _search_one(
         state, batch = select_batch(
             state, capacity=capacity, io_batch=io_batch
         )
-        with jax.named_scope("shared_reads"):
-            # a select, not a scatter: cheaper in the vmapped loop
-            at_hop = jnp.arange(max_hops)[:, None] == state.hops
-            state = state._replace(
-                trail=jnp.where(at_hop, batch[None, :], state.trail))
         mids, md, nids, nd, io_delta, hit_delta = score_page_batch(
             q, data, batch, state, disk_lut, mem_lut,
             capacity=capacity, mode=mode, fetch=fetch,
@@ -937,7 +949,8 @@ def _profile_one(
     )
     state = init_state(
         q, data, disk_lut, beam=beam, k=k, entries=entries,
-        entry_slack=entry_slack, min_entries=min_entries, patience=patience,
+        max_hops=max_hops, io_batch=io_batch, entry_slack=entry_slack,
+        min_entries=min_entries, patience=patience,
     )
 
     def cond(state: BeamState):
@@ -969,7 +982,6 @@ def _profile_one(
             lambda a, b: jnp.where(active, b, a), state, st
         )
         rec = (
-            jnp.where(active, batch, PAD),
             jnp.where(active, io_delta, 0).astype(jnp.int32),
             jnp.where(active, hit_delta, 0).astype(jnp.int32),
             active,
@@ -978,12 +990,14 @@ def _profile_one(
         )
         return new, rec
 
-    final, (pages, ios, hits, active, worst, stall) = jax.lax.scan(
+    # a lane's active steps are a prefix, so its step h wrote row h of
+    # the trail: the trail is the per-hop pages, PAD past the exit
+    final, (ios, hits, active, worst, stall) = jax.lax.scan(
         step, state, None, length=max_hops
     )
     return (
         (final.res_ids, final.res_d, final.io, final.hops, final.cache_hits),
-        (pages, ios, hits, active, worst, stall),
+        (final.trail, ios, hits, active, worst, stall),
     )
 
 
@@ -1192,9 +1206,10 @@ def _exact_top_k(queries, vecs, cand, row_query, first_row, n_rows,
 
     def fold(ids_q, d_q):
         none = jnp.zeros((0,), jnp.int32)
+        # merge never reads the trail
         empty = BeamState(
             cand_ids=none, cand_d=none.astype(jnp.float32),
-            cand_vis=none.astype(bool), page_vis=none.astype(bool),
+            cand_vis=none.astype(bool), trail=none.reshape(0, 0),
             res_ids=jnp.full((k,), PAD, jnp.int32),
             res_d=jnp.full((k,), INF, jnp.float32),
             io=jnp.int32(0), cache_hits=jnp.int32(0), hops=jnp.int32(0))

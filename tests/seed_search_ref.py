@@ -21,11 +21,26 @@ import jax.numpy as jnp
 from repro.core import pq as pq_mod
 from repro.core.config import MemoryMode
 from repro.core.lsh import hash_codes
-from repro.core.search import BeamState, SearchResult
+from repro.core.search import SearchResult
 from repro.kernels import ref
 
 PAD = -1
 INF = jnp.inf
+
+
+class SeedState(NamedTuple):
+    """The seed's per-query loop state, with its own (P,) visited-page
+    bitmap (the optimized loop's ``BeamState`` keeps the trail instead)."""
+
+    cand_ids: jnp.ndarray
+    cand_d: jnp.ndarray
+    cand_vis: jnp.ndarray
+    page_vis: jnp.ndarray
+    res_ids: jnp.ndarray
+    res_d: jnp.ndarray
+    io: jnp.ndarray
+    cache_hits: jnp.ndarray
+    hops: jnp.ndarray
 
 
 class SeedData(NamedTuple):
@@ -63,7 +78,7 @@ def _init_state(q, data, disk_lut, *, beam, k, entries):
     entry_d = _mask_dups_keep_first(entry_ids, entry_d)
     cand_ids = jnp.full((beam,), PAD, jnp.int32).at[:entries].set(entry_ids)
     cand_d = jnp.full((beam,), INF, jnp.float32).at[:entries].set(entry_d)
-    return BeamState(
+    return SeedState(
         cand_ids=cand_ids,
         cand_d=cand_d,
         cand_vis=jnp.zeros((beam,), bool),

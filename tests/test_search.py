@@ -1,7 +1,9 @@
 """End-to-end PageANN search behaviour (Algorithm 2) + memory-mode matrix
 + exact equivalence of the fused/top-k hot path against the frozen seed loop."""
 import dataclasses
+import functools
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -128,15 +130,19 @@ def test_beam_membership_compare_matches_sorted_probe(dataset, hybrid_index,
         ]).astype(np.int32)
         rng.shuffle(cand)
         probe = np.append(nids, search_mod.PAD).astype(np.int32)
-        got = search_mod.in_beam(jnp.asarray(probe), jnp.asarray(cand))
+        got = search_mod.dense_isin(jnp.asarray(probe), jnp.asarray(cand))
         np.testing.assert_array_equal(np.asarray(got), np.isin(probe, cand))
 
         page_vis = rng.random(num_pages) < 0.1
+        # the visited set as the loop keeps it: a trail of the pages
+        trail = np.full(idx.default_params.max_hops * b, search_mod.PAD,
+                        np.int32)
+        trail[:page_vis.sum()] = np.flatnonzero(page_vis)
         state = search_mod.BeamState(
             cand_ids=jnp.asarray(cand),
             cand_d=jnp.zeros((beam,), jnp.float32),
             cand_vis=jnp.zeros((beam,), bool),
-            page_vis=jnp.asarray(page_vis),
+            trail=jnp.asarray(trail.reshape(-1, b)),
             res_ids=jnp.full((10,), search_mod.PAD, jnp.int32),
             res_d=jnp.full((10,), jnp.inf, jnp.float32),
             io=jnp.int32(0), cache_hits=jnp.int32(0), hops=jnp.int32(0),
@@ -159,6 +165,129 @@ def test_beam_membership_compare_matches_sorted_probe(dataset, hybrid_index,
         want = np.where(s[pos] == nids, np.float32(np.inf), base)
         np.testing.assert_array_equal(est_of(state), want)
         assert np.isfinite(want).any() and np.isinf(want).any()
+
+
+def _bitmap_select(cand, d, vis, visited, cap, b):
+    """The seed's b serial argmin picks over a (P,) visited-page bitmap:
+    each pick first retires candidates on visited pages, then marks its own
+    page visited. Returns (expanded flags, batch, bitmap after)."""
+    pad = -1
+    vis, visited = vis.copy(), visited.copy()
+    pages = np.where(cand >= 0, cand // cap, 0)
+    batch = np.full(b, pad, np.int32)
+    for j in range(b):
+        vis |= (cand != pad) & visited[pages]
+        masked = np.where(vis | (cand == pad), np.inf, d)
+        s = int(np.argmin(masked))
+        vis[s] = True
+        if np.isfinite(masked[s]):
+            batch[j] = pages[s]
+            visited[pages[s]] = True
+    return vis, batch, visited
+
+
+@pytest.mark.parametrize("hops,b,beam", [(64, 5, 96), (64, 5, 256),
+                                         (256, 8, 256)],
+                         ids=["64x5-beam96", "64x5-beam256", "256x8-beam256"])
+def test_trail_membership_matches_a_page_bitmap(dataset, hybrid_index, hops,
+                                                b, beam):
+    """The loop's visited set is each lane's trail of scheduled pages, and
+    its three tests are dense compares against it: ``select_batch``'s stale
+    and early tests and ``score_page_batch``'s neighbour test give what a
+    (P,) bitmap of the same pages gives, over random trails with PAD slots
+    and rows, pages repeated across hops, PAD candidates and neighbours,
+    and a lane whose beam is exhausted."""
+    from repro.core import pq as pq_mod
+    from repro.core import search as search_mod
+
+    pad = search_mod.PAD
+    idx = hybrid_index
+    cap, mode = idx.store.capacity, idx.cfg.memory_mode.value
+    num_pages = idx.store.num_pages
+    nbr_ids = np.asarray(idx.data.nbr_ids).copy()
+    nbr_ids[::7, -3:] = pad                 # PAD neighbour slots
+    data = idx.data._replace(nbr_ids=jnp.asarray(nbr_ids))
+    rng = np.random.default_rng(hops * b + beam)
+    lanes = 6
+    # lane 0 at its first hop, lane 1 at its last, lane 2 exhausted
+    at = np.concatenate([[0, hops - 1], rng.integers(1, hops, lanes - 2)])
+    trails, cands, dists, viss, visited = [], [], [], [], []
+    for lane in range(lanes):
+        h = int(at[lane])
+        # about half the pages visited, repeats across hops, PAD slots
+        p = min(1.0, num_pages / (2 * max(h, 1) * b))
+        trail = np.full((hops, b), pad, np.int32)
+        for row in range(h):
+            n = rng.binomial(b, p)
+            trail[row, :n] = rng.choice(num_pages, n, replace=False)
+            rng.shuffle(trail[row])
+        seen = np.zeros(num_pages, bool)
+        seen[trail[trail >= 0]] = True
+        # the beam: ids on visited pages, on any page, and PAD slots
+        on_seen = np.flatnonzero(np.repeat(seen, cap))
+        n_seen = min(beam // 4, len(on_seen))
+        ids = np.concatenate([
+            rng.choice(on_seen, n_seen, replace=False),
+            rng.choice(num_pages * cap, beam // 2, replace=False),
+        ])
+        ids = np.unique(ids)[:beam - beam // 8]
+        cand = np.full(beam, pad, np.int32)
+        cand[:len(ids)] = ids
+        rng.shuffle(cand)
+        d = rng.random(beam).astype(np.float32)
+        d[cand == pad] = np.inf
+        d[rng.random(beam) < 0.05] = np.inf
+        vis = rng.random(beam) < 0.2
+        if lane == 2:
+            vis[:] = True
+        trails.append(trail)
+        cands.append(cand)
+        dists.append(d)
+        viss.append(vis)
+        visited.append(seen)
+
+    def stack(xs):
+        return jnp.asarray(np.stack(xs))
+
+    state = search_mod.BeamState(
+        cand_ids=stack(cands), cand_d=stack(dists), cand_vis=stack(viss),
+        trail=stack(trails),
+        res_ids=jnp.full((lanes, 10), pad, jnp.int32),
+        res_d=jnp.full((lanes, 10), jnp.inf, jnp.float32),
+        io=jnp.zeros(lanes, jnp.int32), cache_hits=jnp.zeros(lanes, jnp.int32),
+        hops=jnp.asarray(at, jnp.int32))
+    new, batch = jax.vmap(functools.partial(
+        search_mod.select_batch, capacity=cap, io_batch=b))(state)
+    new_vis, batch = np.asarray(new.cand_vis), np.asarray(batch)
+    new_trail = np.asarray(new.trail)
+    after = []
+    for lane in range(lanes):
+        vis, want_batch, seen = _bitmap_select(
+            cands[lane], dists[lane], viss[lane], visited[lane], cap, b)
+        np.testing.assert_array_equal(new_vis[lane], vis)
+        np.testing.assert_array_equal(batch[lane], want_batch)
+        want_trail = trails[lane].copy()
+        want_trail[at[lane]] = want_batch
+        np.testing.assert_array_equal(new_trail[lane], want_trail)
+        after.append(seen)
+    assert (batch[2] == pad).all() and (batch[[0, 1]] >= 0).any()
+
+    q = jnp.asarray(dataset[1][0], jnp.float32)
+    disk_lut = pq_mod.pq_lut(q, data.disk_codebooks)
+    mem_lut = pq_mod.pq_lut(q, data.mem_codebooks)
+    score = jax.vmap(lambda bt, st: search_mod.score_page_batch(
+        q, data, bt, st, disk_lut, mem_lut, capacity=cap, mode=mode))
+    out = score(jnp.asarray(batch), new)
+    nids, got = np.asarray(out[2]), np.asarray(out[3])
+    # every mask but the visited test (an all-PAD trail matches no page),
+    # then the bitmap's test on top
+    base = np.asarray(score(jnp.asarray(batch), new._replace(
+        trail=jnp.full_like(new.trail, pad)))[3])
+    pages = np.maximum(nids, 0) // cap
+    on_seen = np.stack([after[lane][pages[lane]] for lane in range(lanes)])
+    np.testing.assert_array_equal(got, np.where(on_seen, np.inf, base))
+    assert (nids == pad).any()
+    assert (np.isfinite(base) & on_seen).any() and np.isfinite(got).any()
 
 
 def test_mem_all_packs_more_vectors_per_page(dataset):

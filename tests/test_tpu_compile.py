@@ -161,6 +161,14 @@ def test_batch_search_executable_compiles(one_chip, monkeypatch):
     # the beam-membership probe is one dense compare: no serial loop (a
     # searchsorted binary search lowers to a while loop of gathers)
     assert not [s for s in scopes if re.search(r"hop_cand_probe/.*while", s)]
+    # the visited set is each lane's trail of pages: no gather reads from
+    # and no scatter writes into a per-lane page table, (Q, P) or (Q * P,)
+    lanes, pages = q.shape[0], index.store.num_pages
+    shapes = dict(re.findall(r"(%[\w.\-]+) = \w+(\[[\d,]*\])", hlo))
+    operands = {shapes[name] for name in re.findall(
+        r"= \S+ (?:gather|scatter)\((%[\w.\-]+)", hlo)}
+    assert operands and not operands & {f"[{lanes},{pages}]",
+                                        f"[{lanes * pages}]"}
 
 
 def test_filtered_search_executables_compile(one_chip, monkeypatch):
