@@ -48,8 +48,8 @@ QUERIES, BATCH = 256, 64
 INSERTS = 64
 TAGS = 10                              # one tag value selects ~10%
 DEFAULT_N = {1: 40000, 4: 8000}
-# beam 64 (benchmarks/common.py) reaches recall@10 0.68 at N = 40000;
-# 256 reaches 0.99. A filtered search widens its beam by the
+# beam 64 (with ``_config``'s other knobs) reaches recall@10 0.68 at
+# N = 40000; 256 reaches 0.99. A filtered search widens its beam by the
 # predicate's oversampling (16x at 10%), so it starts from 64.
 BEAM, FILTERED_BEAM = 256, 64
 
@@ -111,8 +111,8 @@ def _check_recall(args, phase: str, got: float) -> str:
 def _config(dim: int):
     from repro.core import MemoryMode, PageANNConfig
 
-    # benchmarks/common.py's knobs but the beam; 16 PQ subspaces from
-    # d = 128 up
+    # degree 24, build beam 48, 1,024 LSH samples, 12 entries, 64 hops,
+    # HYBRID; 16 PQ subspaces from d = 128 up
     return PageANNConfig(
         dim=dim, graph_degree=24, build_beam=48,
         pq_subspaces=8 if dim <= 32 else 16,

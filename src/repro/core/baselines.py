@@ -17,7 +17,7 @@ them directly comparable with ``core.search`` on the same data.
 :class:`DiskANNIndex` / :class:`StarlingIndex` wrap the raw searches in the
 same :class:`repro.core.protocol.VectorIndex` lifecycle as
 ``PageANNIndex`` — build/from_data → save → load → ``search(queries, k,
-params)`` returning a ``SearchResult`` — so benchmarks and the serving
+params)`` returning a ``SearchResult`` — so tests and the serving
 engine drive all three systems through one code path.
 """
 from __future__ import annotations

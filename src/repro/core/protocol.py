@@ -2,7 +2,7 @@
 
 Every searchable index in this repo — :class:`repro.core.index.PageANNIndex`
 and the DiskANN/Starling baselines in :mod:`repro.core.baselines` — speaks
-the same small surface, so benchmarks sweep all systems through one code
+the same small surface, so tests compare all systems through one code
 path and the serving layer is implementation-agnostic: the
 collection-agnostic :class:`repro.serve.BatchingEngine` batches requests
 per ``(collection, k-bin, params)`` group, and the database-level

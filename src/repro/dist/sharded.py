@@ -10,8 +10,7 @@ recall differences vs the unsharded index come only from per-shard beam
 search quality, which is why :func:`shard_params_for` can shrink the
 per-shard beam: each shard searches a 1/S-size corpus, and the beam needed
 for a given recall shrinks with the corpus.  Recall parity vs the
-unsharded build is CI-gated (``benchmarks/scaleout.py``,
-``tests/test_sharded_store.py``).
+unsharded build is held by ``tests/test_scaleout.py`` at 2 and 4 shards.
 
 Two execution paths share one artifact:
 
@@ -59,9 +58,9 @@ def shard_params_for(base: SearchParams, num_shards: int) -> SearchParams:
     device (each query does less total page-scoring work).  The scaling
     here (beam halved per doubling of shards, floored at the legal
     minimum; smaller io_batch so the shorter walks waste less speculative
-    I/O) was measured on the benchmark corpus at recall parity; the
-    parity gate in ``benchmarks/scaleout.py`` keeps it honest for other
-    configs.
+    I/O) was measured on a clustered corpus at recall parity; the
+    parity tests in ``tests/test_scaleout.py`` keep it honest in every
+    memory mode.
     """
     if num_shards <= 1:
         return base

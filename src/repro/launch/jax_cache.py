@@ -5,8 +5,8 @@ again by the next run, so the place is fixed. Where
 ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
 this module sets nothing; otherwise the cache lives in ``.jax_cache/`` at
 the root of the checkout. Entry points (``chip_smoke.py``,
-``repro.launch.serve``, ``benchmarks.*``) call :func:`use_persistent_cache`
-before their first compile; library code and tests never do.
+``repro.launch.serve``) call :func:`use_persistent_cache` before their
+first compile; library code and tests never do.
 """
 from __future__ import annotations
 

@@ -371,6 +371,35 @@ def small_index():
     return PageANNIndex.build(x, cfg)
 
 
+def test_tracing_leaves_served_results_bit_identical(small_index):
+    """Tracing never changes the compiled program or the dispatch order:
+    one query set served with no tracer, a disabled tracer and an enabled
+    one returns the same ids, distances (to the bit), I/Os and hops."""
+    x = clustered_vectors(N, D, num_clusters=16, seed=0)
+    q = query_vectors(x, 20, seed=11)      # 8 + 8 + a partial batch of 4
+    tracers = {"plain": None, "off": Tracer(enabled=False), "on": Tracer()}
+    served = {}
+    for name, tr in tracers.items():
+        eng = BatchingEngine.from_index(
+            small_index, k=10, batch_size=8, tracer=tr
+        )
+        try:
+            rows = eng.search(q)
+        finally:
+            eng.close()
+        served[name] = {
+            f: np.stack([np.asarray(getattr(r.result, f)) for r in rows])
+            for f in ("ids", "dists", "ios", "hops")
+        }
+    assert len(tracers["on"]) > 0 and len(tracers["off"]) == 0
+    want = served["plain"]
+    for name in ("off", "on"):
+        for f, arr in served[name].items():
+            ref = want[f].view(np.uint32) if f == "dists" else want[f]
+            got = arr.view(np.uint32) if f == "dists" else arr
+            np.testing.assert_array_equal(ref, got, err_msg=f"{name}: {f}")
+
+
 @pytest.mark.parametrize("mode", list(MemoryMode))
 def test_profile_search_matches_batch_search(mode):
     x = clustered_vectors(N, D, num_clusters=16, seed=0)
