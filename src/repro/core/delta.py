@@ -46,6 +46,7 @@ import numpy as np
 
 from repro.core import filter as filter_mod
 from repro.core import search as search_mod
+from repro.core import stream as stream_mod
 from repro.core.config import DeltaParams, SearchParams, resolve_search_params
 from repro.core.filter import CompiledFilter, FilterExpr, MetaArrays
 from repro.kernels import ops
@@ -711,5 +712,5 @@ class MutableIndex:
         is fully resident or has no streaming tier)."""
         fn = getattr(self._state.base, "fetch_stats", None)
         if fn is None:
-            return dict(pages_fetched=0, fetch_hits=0, fetch_wall_s=0.0)
+            return stream_mod.no_fetch_stats()
         return fn()

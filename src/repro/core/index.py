@@ -30,6 +30,7 @@ from repro.core import page_graph as pg_mod
 from repro.core import plan as plan_mod
 from repro.core import pq as pq_mod
 from repro.core import search as search_mod
+from repro.core import stream as stream_mod
 from repro.core import vamana as vamana_mod
 from repro.core.config import (
     AdaptiveParams,
@@ -497,9 +498,10 @@ class PageANNIndex:
 
     def fetch_stats(self) -> dict:
         """Streaming-tier counters (``pages_fetched`` / ``fetch_hits`` /
-        ``fetch_wall_s``); zeros when fully resident."""
+        ``fetch_wall_s`` / ``fetch_calls`` / ``staged_slots``); zeros when
+        fully resident."""
         if self.fetcher is None:
-            return dict(pages_fetched=0, fetch_hits=0, fetch_wall_s=0.0)
+            return stream_mod.no_fetch_stats()
         return self.fetcher.fetch_stats()
 
     def vectors_by_original_id(self) -> np.ndarray:

@@ -482,12 +482,10 @@ def score_page_batch(
             # host fetch only what the device lacks; everything else
             # (resident pages, unselected PAD lanes) is masked to -1 and
             # comes back as a zero record whose scores are discarded by the
-            # per-lane merge / downstream validity masks
+            # per-lane merge / downstream validity masks. The hook scopes
+            # its host callback ``hop_fetch``
             missing = jnp.where(fetched & ~resident, safe, PAD)
-    if fetch is not None:
-        with jax.named_scope("hop_fetch"):
             staged = fetch(missing)
-        with jax.named_scope("hop_scan"):
             ex_r, est_r = ops.page_scan(
                 data.page_recs, jnp.where(resident, slot, 0), q, disk_lut,
                 **scan,
@@ -791,6 +789,70 @@ def batch_search(
 # streaming entry point: resident subset on device, misses fetched per hop
 # --------------------------------------------------------------------------
 
+def fetch_rungs(slots: int) -> tuple[int, ...]:
+    """Sizes of the packed buffer a streamed hop may ship for ``slots``
+    request slots: none, then an eighth, a quarter, half and all of them,
+    each rounded up to a multiple of 8 (the record tile's sublanes)."""
+    up = lambda n: -(-n // 8) * 8  # noqa: E731
+    return tuple(sorted({0} | {up(-(-slots // d)) for d in (8, 4, 2, 1)}))
+
+
+def _fetch_hook(fetcher):
+    """The streamed hop's host fetch: ``fetch(missing)`` returns the
+    records of a lane's (b,) ``missing`` page ids, zeros where PAD.
+
+    Called by one lane, it is one ``pure_callback`` of the full shape.
+    Under ``vmap`` (``batch_search``'s lanes) its rule sees the whole
+    (Q, b) batch: it counts the live ids, packs them at the front of a
+    buffer whose static size is the smallest rung of ``fetch_rungs(Q*b)``
+    that holds them (a ``lax.switch`` on the count, with no batch axis),
+    asks the host for that buffer alone, and expands it back to the
+    lanes' slots. A hop with no live id makes no host call. Only the
+    callback runs under ``hop_fetch``; the packing and the expansion run
+    in the caller's scope.
+    """
+    rows, lanes = fetcher.record_shape
+
+    def records(ids):
+        with jax.named_scope("hop_fetch"):
+            return jax.pure_callback(
+                fetcher,
+                jax.ShapeDtypeStruct(ids.shape + (rows, lanes), jnp.float32),
+                ids,
+            )
+
+    @jax.custom_batching.custom_vmap
+    def fetch(missing):
+        return records(missing)
+
+    @fetch.def_vmap
+    def _(axis_size, in_batched, missing):
+        if not in_batched[0]:
+            missing = jnp.broadcast_to(missing, (axis_size,) + missing.shape)
+        flat = missing.reshape(-1)
+        live = flat >= 0
+        n = live.sum()
+        slot = jnp.cumsum(live) - 1       # a live id's place in the pack
+
+        def rung(size):
+            def branch():
+                if size == 0:
+                    return jnp.zeros(flat.shape + (rows, lanes), jnp.float32)
+                ids = jnp.full((size,), PAD, flat.dtype).at[
+                    jnp.where(live, slot, size)].set(flat, mode="drop")
+                packed = records(ids)
+                got = packed[jnp.clip(slot, 0, size - 1)]
+                return jnp.where(live[:, None, None], got, 0.0)
+            return branch
+
+        rungs = fetch_rungs(flat.size)
+        staged = jax.lax.switch(
+            (n > jnp.asarray(rungs[:-1])).sum(), [rung(c) for c in rungs])
+        return staged.reshape(missing.shape + (rows, lanes)), True
+
+    return fetch
+
+
 @functools.lru_cache(maxsize=32)
 def _stream_search_fn(
     fetcher, params: SearchParams, capacity: int, mode: str,
@@ -807,17 +869,7 @@ def _stream_search_fn(
     the filter shape participates by value, one executable per shape.
     """
     kwargs = _impl_kwargs(params, capacity, mode)
-    rows, lanes = fetcher.record_shape
-
-    def fetch(ids: jnp.ndarray) -> jnp.ndarray:
-        # expand_dims: under vmap the fetcher is called ONCE per hop with
-        # the batch axis leading, not once per query
-        return jax.pure_callback(
-            fetcher,
-            jax.ShapeDtypeStruct(ids.shape + (rows, lanes), jnp.float32),
-            ids,
-            vmap_method="expand_dims",
-        )
+    fetch = _fetch_hook(fetcher)
 
     @jax.jit
     def fn(queries, data, valid, meta=None, fvals=None):
@@ -844,8 +896,8 @@ def stream_search(
     """``batch_search`` over a budgeted index: ``data.page_recs`` holds
     only the resident page subset, and each hop's misses are pulled from
     the host memmap by ``fetcher`` (a ``core.stream.PageFetcher``) through
-    a batched ``pure_callback`` — ONE host round-trip per hop for the
-    whole query batch.
+    ``_fetch_hook``: at most ONE host round-trip per hop for the whole
+    query batch, shipping only the records the hop asked for.
 
     Results are bit-identical to the fully resident ``batch_search`` on
     the same artifact: the staged batch is scored by

@@ -40,6 +40,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import distributed as dist
 from repro.core import persist
+from repro.core import stream as stream_mod
 from repro.core.config import PageANNConfig, SearchParams
 from repro.core.index import PageANNIndex
 from repro.core.config import resolve_search_params
@@ -147,7 +148,7 @@ class ShardedPageStore:
         )
 
     def fetch_stats(self) -> dict:
-        out = dict(pages_fetched=0, fetch_hits=0, fetch_wall_s=0.0)
+        out = stream_mod.no_fetch_stats()
         for s in self.shards:
             fs = s.fetch_stats()
             for key in out:

@@ -280,6 +280,10 @@ _ENGINE_FIELDS = {
                    "Page requests served by the host staging cache"),
     "fetch_wall_s": ("fetch_wall_seconds_total", "counter",
                      "Wall seconds inside the host page-fetch callback"),
+    "fetch_calls": ("fetch_calls_total", "counter",
+                    "Host page-fetch round trips (streaming)"),
+    "staged_slots": ("staged_slots_total", "counter",
+                     "Page-record slots shipped to the device (streaming)"),
     "semantic_hits": ("semantic_hits_total", "counter",
                       "Submits served from the semantic query cache"),
     "semantic_misses": ("semantic_misses_total", "counter",

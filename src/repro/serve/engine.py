@@ -95,7 +95,8 @@ class EngineMetrics(NamedTuple):
     snapshot instant. Monotonicity contract: the cumulative counters
     (``requests``, ``batches``, ``inserts``, ``deletes``,
     ``compactions``, ``early_exits``, ``compile_*``, ``pages_fetched``,
-    ``fetch_hits``, ``fetch_wall_s``, ``semantic_*``, ``hops_total``,
+    ``fetch_hits``, ``fetch_wall_s``, ``fetch_calls``, ``staged_slots``,
+    ``semantic_*``, ``hops_total``,
     ``hop_page_reads``, ``hop_shared_reads``) never decrease
     across successive snapshots of one engine, and no counter can run
     ahead of the ``requests`` it belongs to within a snapshot — safe to
@@ -129,6 +130,8 @@ class EngineMetrics(NamedTuple):
     pages_fetched: int = 0     # page records read off the host memmap
     fetch_hits: int = 0        # page requests served by the staging cache
     fetch_wall_s: float = 0.0  # wall seconds inside the host fetch callback
+    fetch_calls: int = 0       # host fetch round trips
+    staged_slots: int = 0      # page-record slots shipped to the device
     # traversal cost per request (trailing window over SearchResult
     # counters) — where adaptive early termination shows up in serving
     mean_hops: float = 0.0     # mean while_loop hops per request
@@ -200,8 +203,8 @@ class _Collection(NamedTuple):
     insert_fn: Callable | None
     delete_fn: Callable | None
     compact_fn: Callable | None
-    # () -> {pages_fetched, fetch_hits, fetch_wall_s}; None when the
-    # backend has no streaming page tier
+    # () -> {pages_fetched, fetch_hits, fetch_wall_s, fetch_calls,
+    # staged_slots}; None when the backend has no streaming page tier
     fetch_stats_fn: Callable | None = None
     # whether search_fn takes a 4th positional arg (a FilterExpr, or one
     # QueryPlan per batch row where plans_fn is set): True for
@@ -1133,7 +1136,7 @@ class BatchingEngine:
         """
         with self._lock:
             cc = self._compile_cache.stats()
-            pages_fetched = fetch_hits = 0
+            pages_fetched = fetch_hits = fetch_calls = staged_slots = 0
             fetch_wall_s = 0.0
             for c in self._collections.values():
                 if c.fetch_stats_fn is None:
@@ -1142,6 +1145,8 @@ class BatchingEngine:
                 pages_fetched += int(fs.get("pages_fetched", 0))
                 fetch_hits += int(fs.get("fetch_hits", 0))
                 fetch_wall_s += float(fs.get("fetch_wall_s", 0.0))
+                fetch_calls += int(fs.get("fetch_calls", 0))
+                staged_slots += int(fs.get("staged_slots", 0))
             lat = np.asarray(self._latencies_ms, np.float64)
             hops_win = np.asarray(self._hops_win, np.float64)
             ios_win = np.asarray(self._ios_win, np.float64)
@@ -1179,6 +1184,8 @@ class BatchingEngine:
                 pages_fetched=pages_fetched,
                 fetch_hits=fetch_hits,
                 fetch_wall_s=fetch_wall_s,
+                fetch_calls=fetch_calls,
+                staged_slots=staged_slots,
                 mean_hops=float(hops_win.mean()) if len(hops_win) else 0.0,
                 p99_hops=(
                     float(np.percentile(hops_win, 99)) if len(hops_win) else 0.0

@@ -97,9 +97,16 @@ def test_lowered_search_names_every_hop_stage_it_runs(path, corpus,
     hlo = _hlo(index, jnp.asarray(q), fetcher=index.fetcher)
     assert _scopes(hlo) == want
     if path == "streamed":
-        # the host callback, and nothing else, runs under hop_fetch
+        # the host callback, and nothing else, runs under hop_fetch: the
+        # switch over the packed buffer's sizes, the packing and the
+        # expansion run under hop_scan
         fetch_ops = re.findall(r'op_name="[^"]*/hop_fetch/([^"]*)"', hlo)
         assert fetch_ops and set(fetch_ops) == {"pure_callback"}
+        assert re.search(r'op_name="[^"]*/hop_scan/cond"', hlo)
+        assert not re.search(r'op_name="[^"]*/hop_fetch/[^"]*cond', hlo)
+    else:
+        # no fetch: no host callback and no branch in the program
+        assert not re.search(r"callback|conditional\(", hlo)
 
 
 @pytest.mark.parametrize("mode", [MemoryMode.HYBRID, MemoryMode.DISK_ONLY],

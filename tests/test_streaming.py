@@ -4,6 +4,7 @@ fully resident search, the host fetcher, and the serving-surface plumbing
 import json
 import os
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -18,6 +19,7 @@ from repro.core import (
 )
 from repro.core import baselines as bl
 from repro.core import persist
+from repro.core import search as search_mod
 from repro.core import stream as stream_mod
 from repro.core.vamana import brute_force_knn
 from repro.data.pipeline import clustered_vectors, query_vectors
@@ -135,7 +137,8 @@ def test_streamed_search_bit_identical_every_mode(dataset, mode_artifact):
     fs = streamed.fetch_stats()
     assert fs["pages_fetched"] > 0          # the streaming path really ran
     assert full.fetch_stats() == dict(
-        pages_fetched=0, fetch_hits=0, fetch_wall_s=0.0
+        pages_fetched=0, fetch_hits=0, fetch_wall_s=0.0, fetch_calls=0,
+        staged_slots=0,
     )
 
 
@@ -240,7 +243,8 @@ def test_fetcher_lru_eviction_stays_correct():
     assert fs["pages_fetched"] >= 6                   # capacity-1 thrashing
     f.reset_stats()
     assert f.fetch_stats() == dict(
-        pages_fetched=0, fetch_hits=0, fetch_wall_s=0.0, wall_window=()
+        pages_fetched=0, fetch_hits=0, fetch_wall_s=0.0, fetch_calls=0,
+        staged_slots=0, wall_window=()
     )
 
 
@@ -253,6 +257,150 @@ def test_fetcher_counters_accumulate():
     assert fs["pages_fetched"] == 3
     assert fs["fetch_hits"] == 2
     assert fs["fetch_wall_s"] >= 0.0
+
+
+def test_fetcher_fills_a_packed_request_in_flat_order():
+    """Live ids at the front, PAD behind: the map's rows in the same
+    order, repeats included, and zeros behind them."""
+    rng = np.random.default_rng(1)
+    recs = rng.standard_normal((9, 3, 8)).astype(np.float32)
+    f = stream_mod.PageFetcher(recs, stage_pages=4)
+    for live in ([4, 1, 7, 1, 0, 8], [8, 8], [2, 5, 3, 6, 0, 1, 4, 7], []):
+        ids = np.array(live + [stream_mod.PAD] * 3)
+        out = f(ids)
+        assert out.shape == (len(ids), 3, 8)
+        np.testing.assert_array_equal(out[:len(live)], recs[live])
+        assert not out[len(live):].any()
+
+
+def test_fetcher_counts_a_repeated_page_one_miss_then_hits():
+    recs = np.zeros((5, 2, 8), np.float32)
+    f = stream_mod.PageFetcher(recs, stage_pages=1)
+    f(np.array([3, 3, stream_mod.PAD, 3, 2]))
+    fs = f.fetch_stats()
+    # every live slot is one hit or one miss; each distinct page misses once
+    assert (fs["pages_fetched"], fs["fetch_hits"]) == (2, 2)
+    assert (fs["fetch_calls"], fs["staged_slots"]) == (1, 5)
+
+
+@pytest.mark.parametrize("stage_pages, calls, misses", [
+    # [0, 1, 0] uses 0 last, so the 2-page cache evicts 1 for 2
+    (2, [[0, 1, 0], [2], [0], [1]], [2, 1, 0, 1]),
+    # a page touched by a hit is younger than one staged before it
+    (2, [[0], [1], [0], [2], [0], [1]], [1, 1, 0, 1, 0, 1]),
+    # one page: only the call's last page stays, so alternation thrashes
+    (1, [[0, 1, 0], [0], [1], [0], [1, 1]], [2, 0, 1, 1, 1]),
+    # more distinct pages in a call than the cache holds: the last ones stay
+    (2, [[0, 1, 2, 3], [3], [2], [0]], [4, 0, 0, 1]),
+])
+def test_fetcher_evicts_the_least_recently_used(stage_pages, calls, misses):
+    rng = np.random.default_rng(2)
+    recs = rng.standard_normal((4, 2, 8)).astype(np.float32)
+    f = stream_mod.PageFetcher(recs, stage_pages=stage_pages)
+    got = []
+    for ids in calls:
+        before = f.fetch_stats()["pages_fetched"]
+        np.testing.assert_array_equal(f(np.array(ids)), recs[ids])
+        got.append(f.fetch_stats()["pages_fetched"] - before)
+    assert got == misses
+    fs = f.fetch_stats()
+    assert fs["pages_fetched"] + fs["fetch_hits"] == sum(map(len, calls))
+
+
+class SpyFetcher(stream_mod.PageFetcher):
+    """A fetcher that keeps the shape and the live count of each call."""
+
+    def __init__(self, recs, **kw):
+        super().__init__(recs, **kw)
+        self.calls = []
+
+    def __call__(self, ids):
+        ids = np.asarray(ids)
+        self.calls.append((ids.shape, int((ids >= 0).sum())))
+        return super().__call__(ids)
+
+
+@pytest.mark.parametrize("lanes, b", [(8, 5), (3, 2), (1, 5)])
+def test_batch_fetch_ships_the_smallest_rung_that_holds_the_hop(lanes, b):
+    """Under vmap the hook packs a hop's live ids into the smallest rung of
+    the ladder that holds them, asks the host for that buffer alone, and
+    gives each lane its records back; a hop with no live id asks
+    nothing."""
+    import jax
+
+    rng = np.random.default_rng(3)
+    recs = rng.standard_normal((64, 3, 8)).astype(np.float32)
+    spy = SpyFetcher(recs)
+    fetch = jax.jit(jax.vmap(search_mod._fetch_hook(spy)))
+    rungs = search_mod.fetch_rungs(lanes * b)
+    assert rungs[0] == 0 and rungs[-1] >= lanes * b
+    assert all(r % 8 == 0 for r in rungs)
+    shipped = []
+    for live in sorted({0, 1, *rungs[1:-1], lanes * b}):
+        live = min(live, lanes * b)
+        missing = np.full(lanes * b, stream_mod.PAD)
+        missing[rng.choice(lanes * b, live, replace=False)] = rng.integers(
+            0, 64, live)
+        missing = missing.reshape(lanes, b)
+        spy.calls.clear()
+        got = np.asarray(fetch(missing))
+        want = np.where((missing >= 0)[..., None, None],
+                        recs[np.maximum(missing, 0)], 0.0)
+        np.testing.assert_array_equal(got, want)
+        size = min(r for r in rungs if r >= live)
+        assert spy.calls == ([] if live == 0 else [((size,), live)])
+        shipped += [size] if live else []
+    fs = spy.fetch_stats()
+    assert fs["fetch_calls"] == len(shipped)
+    assert fs["staged_slots"] == sum(shipped)
+
+
+def test_fetch_rungs_follow_the_batch():
+    assert search_mod.fetch_rungs(64 * 5) == (0, 40, 80, 160, 320)
+    assert search_mod.fetch_rungs(5) == (0, 8)
+    assert search_mod.fetch_rungs(12 * 5) == (0, 8, 16, 32, 64)
+
+
+@pytest.mark.parametrize("fraction, lanes, every_rung, skips", [
+    (0.5, 12, True, True),    # every rung, and a hop with no live slot
+    (0.25, 4, True, False),
+    (0.9, 1, True, True),     # most hops find their pages resident
+])
+def test_streamed_search_ships_packed_buffers(dataset, hybrid_artifact,
+                                             fraction, lanes, every_rung,
+                                             skips):
+    """The streamed search through a spy fetcher: bit for bit the resident
+    answers, every buffer a rung of the ladder that its live ids fill past
+    half (or the smallest rung), no call for a hop whose pages are all
+    resident, and the counters the sum of the calls."""
+    _, q, _ = dataset
+    q = q[:lanes]
+    full = PageANNIndex.load(hybrid_artifact)
+    streamed = PageANNIndex.load(hybrid_artifact,
+                                 memory_budget=MemoryBudget(fraction=fraction))
+    params = streamed.default_params
+    kw = dict(capacity=full.store.capacity, mode=full.cfg.memory_mode.value)
+    spy = SpyFetcher(streamed.fetcher._recs)
+    got = search_mod.stream_search(jnp.asarray(q), streamed.data, params,
+                                   fetcher=spy, **kw)
+    want = search_mod.batch_search(jnp.asarray(q), full.data, params, **kw)
+    for field in want._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(want, field)),
+                                      np.asarray(getattr(got, field)),
+                                      err_msg=field)
+    rungs = search_mod.fetch_rungs(lanes * params.io_batch)
+    sizes = [shape[0] for shape, _ in spy.calls]
+    assert (set(sizes) == set(rungs[1:])) is every_rung
+    for (size,), live in spy.calls:
+        assert 0 < live <= size and (live > size // 2 or size == rungs[1])
+    # the loop ran max(hops) times; a hop whose pages were all resident
+    # called nothing
+    assert (len(spy.calls) < int(np.max(got.hops))) is skips
+    fs = spy.fetch_stats()
+    assert fs["fetch_calls"] == len(spy.calls)
+    assert fs["staged_slots"] == sum(sizes)
+    assert fs["pages_fetched"] + fs["fetch_hits"] == sum(
+        live for _, live in spy.calls)
 
 
 # --------------------------------------------- mutable tier over streaming

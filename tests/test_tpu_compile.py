@@ -171,6 +171,54 @@ def test_batch_search_executable_compiles(one_chip, monkeypatch):
                                         f"[{lanes * pages}]"}
 
 
+def test_streamed_search_executable_compiles(one_chip, monkeypatch,
+                                            tmp_path):
+    """The budgeted search at the cell's shape, d = 128 and 64 queries of
+    io batch 5: each hop's host callback inside the ``lax.switch`` over
+    the packed buffer's sizes, in the vmapped while loop, compiles for the
+    chip, and only the callback runs under ``hop_fetch``."""
+    from repro.core import MemoryBudget, MemoryMode, PageANNConfig
+    from repro.core import PageANNIndex, load_index
+    from repro.core import search as search_mod
+    from repro.data.pipeline import clustered_vectors
+    from repro.kernels import ops
+
+    d, batch = 128, 64
+    x = clustered_vectors(400, d, num_clusters=8, seed=0)
+    cfg = PageANNConfig(
+        dim=d, graph_degree=16, build_beam=24, pq_subspaces=16,
+        lsh_sample=256, memory_mode=MemoryMode.HYBRID,
+    )
+    PageANNIndex.build(x, cfg).save(str(tmp_path / "idx"))
+    index = load_index(str(tmp_path / "idx"),
+                       memory_budget=MemoryBudget(fraction=0.25))
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    data = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            np.shape(a), jnp.asarray(a).dtype, sharding=one_chip
+        ),
+        index.data,
+    )
+    params = index.default_params
+    fn = search_mod._stream_search_fn(
+        index.fetcher, params, index.store.capacity, cfg.memory_mode.value)
+    try:
+        hlo = fn.lower(
+            jax.ShapeDtypeStruct((batch, d), jnp.float32, sharding=one_chip),
+            data,
+            jax.ShapeDtypeStruct((batch,), jnp.bool_, sharding=one_chip),
+        ).compile().as_text()
+    finally:
+        jax.clear_caches()
+    rungs = search_mod.fetch_rungs(batch * params.io_batch)
+    assert rungs == (0, 40, 80, 160, 320)
+    fetch = set(re.findall(r'op_name="([^"]*/hop_fetch/[^"]*)"', hlo))
+    # one callback per rung but the first, each under hop_scan's switch
+    assert len(fetch) == len(rungs) - 1
+    assert all(re.search(r"/hop_scan/cond/branch_\d+_fun/hop_fetch/"
+                         r"pure_callback$", s) for s in fetch)
+
+
 def test_filtered_search_executables_compile(one_chip, monkeypatch):
     """The filtered deployment's two programs at d = 192: the graph path
     (``GRAPH_LANES`` queries a program) with a bag predicate's values
